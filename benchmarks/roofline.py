@@ -1,7 +1,8 @@
 """Roofline analysis: three terms per (arch × shape × mesh) cell.
 
-Hardware model (TPU v5e, from the brief):
-  peak = 197 TFLOP/s bf16/chip, HBM = 819 GB/s/chip, ICI ≈ 50 GB/s/link.
+Hardware model (TPU v5e, ``repro.obs.machine.TPU_V5E``, Google Cloud "TPU
+v5e" page): peak = 197 TFLOP/s bf16/chip, HBM = 819 GB/s/chip, ICI =
+1,600 Gbit/s (200 GB/s).
 
 Term sources:
   * compute  = executed_FLOPs_per_chip / peak

@@ -283,6 +283,10 @@ def execute(
     """
     P, T, IW = tx_shards.shape
     spmd, mesh, backend = _auto_spmd(P, spmd, mesh)
+    if mesh is not None:
+        from repro.store.reader import place_on_mesh
+
+        tx_shards = place_on_mesh(tx_shards, mesh)
     phase_ms = {"plan": 0.0, "exchange": 0.0, "mine": 0.0, "merge": 0.0}
 
     tr = obs_trace.TRACER
@@ -317,15 +321,9 @@ def execute(
     anc_b = jnp.broadcast_to(
         jnp.asarray(plan.ancestor_masks), (P, A, n_items)
     )
-    # one partial per execute(): it is a static jit arg of mine_seeded, so a
-    # stable identity keeps all rounds on the same compiled executable
     from repro.kernels import ops
 
-    multi_support_fn = partial(
-        ops.multi_extension_supports,
-        use_mxu=params.use_mxu,
-        force=params.force,
-    )
+    _, multi_support_fn = ops.support_fns(params.force, params.use_mxu)
     p3 = spmd(
         partial(phases.phase3_exchange, axis_name=AXIS, capacity=cap), P, mesh
     )

@@ -16,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core import bitmap as bm
 from repro.core import eclat, mfi, pbec, phases, sampling, schedule
+from repro.kernels import ops as kernel_ops
 from repro.obs import metrics as obs_metrics
 from repro.obs import profile as obs_profile
 from repro.obs import progress as obs_progress
@@ -51,8 +52,7 @@ class FimiParams:
     max_classes: int = 512
     eclat: eclat.EclatConfig = eclat.EclatConfig(max_out=8192, max_stack=2048)
     mfi: mfi.MFIConfig = mfi.MFIConfig(max_out=2048, max_stack=2048)
-    support_fn: Optional[Callable] = None   # Phase-4 single-prefix kernel plug-in
-    multi_support_fn: Optional[Callable] = None  # Phase-4 fused [K,I] kernel plug-in
+    force: Optional[str] = None         # kernel backend pin (kernels.ops)
 
 
 @dataclasses.dataclass
@@ -90,7 +90,8 @@ def shard_map_spmd(fn, P: int, mesh):
 
     shard_map keeps the mapped dim (local size 1) where vmap removes it; the
     squeeze/unsqueeze wrapper gives both combinators identical semantics so
-    the phase functions are written once.
+    the phase functions are written once.  The result is jitted: an eager
+    shard_map traces and compiles again on every call.
     """
     from jax.sharding import PartitionSpec as PS
 
@@ -99,23 +100,9 @@ def shard_map_spmd(fn, P: int, mesh):
         out = fn(*args)
         return jax.tree.map(lambda a: jnp.asarray(a)[None], out)
 
-    if hasattr(jax, "shard_map"):  # newer JAX: top-level API, check_vma kwarg
-        return jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=PS(AXIS),
-            out_specs=PS(AXIS),
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        body,
-        mesh=mesh,
-        in_specs=PS(AXIS),
-        out_specs=PS(AXIS),
-        check_rep=False,
-    )
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=PS(AXIS), out_specs=PS(AXIS), check_vma=False
+    ))
 
 
 # ---------------------------------------------------------------------------
@@ -156,9 +143,14 @@ def run(
                 tx_shards, P, host_budget_blocks=host_budget_blocks,
                 reader=reader,
             ))
+    if mesh is not None:
+        from repro.store.reader import place_on_mesh
+
+        tx_shards = place_on_mesh(tx_shards, mesh)
     P, T, IW = tx_shards.shape
     n_tx = P * T
     abs_minsup = int(np.ceil(params.min_support_rel * n_tx))
+    support_fn, multi_support_fn = kernel_ops.support_fns(params.force)
 
     n_db = params.n_db_sample or sampling.db_sample_size(
         params.eps_db, params.delta_db
@@ -184,6 +176,8 @@ def run(
         eclat_cfg=params.eclat,
         mfi_cfg=params.mfi,
         variant=variant_dev,
+        support_fn=support_fn,
+        multi_support_fn=multi_support_fn,
     )
     keys = jnp.broadcast_to(key, (P, *key.shape))
     minsup_rel = jnp.broadcast_to(
@@ -230,7 +224,8 @@ def run(
         fs_packed = _coverage_sample_host(M, n_fs, n_items, key)
     else:  # "seq": p_1 mines the MFIs of D̃ sequentially (Alg. 12)
         r = mfi.mine_all_candidates(
-            sample_bitdb, sample_minsup, config=params.mfi
+            sample_bitdb, sample_minsup, config=params.mfi,
+            support_fn=support_fn,
         )
         n = int(r.n_out)
         valid = np.zeros(r.items.shape[0], bool)
@@ -249,7 +244,7 @@ def run(
     # ---------------- Phase 2 ------------------------------------------------
     def ext_supports(prefix: np.ndarray) -> np.ndarray:
         tid = bm.tidlist_of_itemset(sample_bitdb, jnp.asarray(prefix))
-        return np.asarray(bm.extension_supports(sample_bitdb.item_bits, tid))
+        return np.asarray(support_fn(sample_bitdb.item_bits, tid))
 
     with tr.span("fimi/phase2_partition", scheduler=params.scheduler):
         classes = pbec.partition(
@@ -321,8 +316,7 @@ def run(
         axis_name=AXIS,
         n_items=n_items,
         eclat_cfg=params.eclat,
-        support_fn=params.support_fn,
-        multi_support_fn=params.multi_support_fn,
+        multi_support_fn=multi_support_fn,
     )
     keys4 = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(P))
     slab = out3.slab.reshape(P, -1, IW) if out3.slab.ndim == 2 else out3.slab

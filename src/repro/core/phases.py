@@ -38,26 +38,15 @@ _U32 = jnp.uint32
 # ---------------------------------------------------------------------------
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a named axis — ``jax.lax.axis_size`` compat shim.
-
-    ``jax.lax.axis_size`` only exists on newer JAX; on older versions
-    ``psum`` of an unmapped Python constant folds to ``1 * P`` at trace time
-    under both ``vmap`` and ``shard_map``, so the result stays a Python int
-    and remains usable for static shapes.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
+@partial(jax.jit, static_argnames=("n_items",))
 def vertical_from_slab(
     slab: jnp.ndarray, valid: jnp.ndarray, n_items: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Horizontal packed slab ``uint32[T, IW]`` (+ row-valid mask) → vertical
     ``item_bits uint32[I, n_words(T)]`` and the valid-tid bitmap.
 
-    The transpose lives on device: unpack → mask → transpose → pack.
+    The transpose lives on device: unpack → mask → transpose → pack, jitted
+    so the 32×-wide unpacked intermediates fuse instead of being held.
     """
     dense = bm.unpack_bool(slab, n_items) & valid[:, None]   # [T, I]
     item_bits = bm.pack_bool(dense.T)                        # [I, W]
@@ -69,7 +58,9 @@ def seed_tidlists(
     item_bits: jnp.ndarray, seed_prefix: jnp.ndarray, valid_tid: jnp.ndarray
 ) -> jnp.ndarray:
     """T(U_k) for K packed seed prefixes — batched AND-reduce (`Prepare-
-    Tidlists`, Alg. 20, as one vectorized op)."""
+    Tidlists`, Alg. 20).  Mapped over seeds in batches of 8: one vmap over
+    all K would hold a ``[K, I, W]`` select, 32 GB at K=640 on a
+    100,000-transaction slab."""
 
     def one(prefix_bool):
         rows = jnp.where(prefix_bool[:, None], item_bits, _U32(0xFFFFFFFF))
@@ -78,7 +69,7 @@ def seed_tidlists(
         )
         return tid & valid_tid
 
-    return jax.vmap(one)(seed_prefix)
+    return jax.lax.map(one, seed_prefix, batch_size=8)
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +129,8 @@ def phase1_device(
     eclat_cfg: eclat.EclatConfig,
     mfi_cfg: mfi.MFIConfig,
     variant: str,                 # "reservoir" | "par"
+    support_fn,                   # kernels.ops.support_fns plug-ins
+    multi_support_fn,
 ) -> Phase1DeviceOut:
     """Device part of Phase 1 (Algs. 12/13/14 lines 1–9).
 
@@ -147,7 +140,7 @@ def phase1_device(
        through a local reservoir (reservoir variant) or collecting MFI
        candidates M_i (par variant).
     """
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     k_samp, k_res = jax.random.split(jax.random.fold_in(key, jax.lax.axis_index(axis_name)))
 
     rows = bm.sample_transactions(local_tx, k_samp, n_sample_per_proc, n_tx_local)
@@ -176,7 +169,7 @@ def phase1_device(
     )
 
     # support-ascending global item order for the 1-prefix classes
-    root_supp = bm.extension_supports(item_bits, valid_tid)
+    root_supp = support_fn(item_bits, valid_tid)
     frequent_item = root_supp >= min_support
     order = jnp.argsort(jnp.where(frequent_item, root_supp, jnp.iinfo(jnp.int32).max))
 
@@ -205,6 +198,7 @@ def phase1_device(
                 eclat_cfg, reservoir_size=reservoir_size, count_only=True
             ),
             n_items=n_items,
+            multi_support_fn=multi_support_fn,
         )
         # The stream contains every FI of D̃ with |W| ≥ 2; singleton FIs are
         # exactly the class prefixes, which the partitioner handles through
@@ -232,6 +226,7 @@ def phase1_device(
             min_support,
             config=mfi_cfg,
             n_items=n_items,
+            support_fn=support_fn,
         )
         return Phase1DeviceOut(
             sample_db=sample_db,
@@ -275,13 +270,15 @@ def phase3_exchange(
     tournament of Alg. 18 — see DESIGN.md).  Overflow is *counted*, never
     silently dropped.
     """
-    P = axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     T = local_tx.shape[0]
 
-    # contains[t, k]: U_k ⊆ t
-    contains = bm.is_subset_packed(
-        class_prefix_packed[None, :, :], local_tx[:, None, :]
-    )  # [T, C]
+    # contains[t, k]: U_k ⊆ t, mapped over classes in batches so the
+    # [T, C, IW] difference is never held at once
+    contains = jax.lax.map(
+        lambda u: bm.is_subset_packed(u[None, :], local_tx),
+        class_prefix_packed, batch_size=32,
+    ).T  # [T, C]
     contains = contains & class_valid[None, :] & local_valid[:, None]
     dest_onehot = jax.nn.one_hot(class_assign, P, dtype=jnp.bool_)  # [C, P]
     need = jnp.einsum("tc,cp->tp", contains, dest_onehot) > 0       # [T, P]

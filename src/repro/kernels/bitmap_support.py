@@ -23,14 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_U32 = jnp.uint32
-
-
-def _popcount_swar(x: jnp.ndarray) -> jnp.ndarray:
-    x = x - ((x >> 1) & _U32(0x55555555))
-    x = (x & _U32(0x33333333)) + ((x >> 2) & _U32(0x33333333))
-    x = (x + (x >> 4)) & _U32(0x0F0F0F0F)
-    return ((x * _U32(0x01010101)) >> 24).astype(jnp.int32)
+from repro.core.bitmap import popcount_u32 as popcount
 
 
 def _kernel(items_ref, tid_ref, out_ref):
@@ -41,7 +34,7 @@ def _kernel(items_ref, tid_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     tile = items_ref[...] & tid_ref[...]            # [BI, BW] & [1, BW]
-    partial = _popcount_swar(tile).sum(axis=1, keepdims=True)  # [BI, 1]
+    partial = popcount(tile).sum(axis=1, keepdims=True)  # [BI, 1]
     out_ref[...] += partial
 
 

@@ -23,7 +23,7 @@ Unlike those kernels the reduced word axis must be *fully resident* per grid
 step (the zero test needs the complete count before thresholding), which is
 free here: the item-word axis IW = n_words(n_items) is a few words.  The
 grid is ``(S, F/BF, T/BT)`` with T minormost (sequential on TPU) so the
-``[1, BF]`` int32 accumulator lives in its output block across T steps.
+``[1, 1, BF]`` int32 accumulator lives in its output block across T steps.
 
 Row-padding trick: T and F pad to tile multiples, and a padded all-zero
 transaction row would falsely "contain" the empty itemset.  The wrapper
@@ -40,14 +40,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.bitmap import popcount_u32 as popcount
+
 _U32 = jnp.uint32
-
-
-def _popcount_swar(x):
-    x = x - ((x >> 1) & _U32(0x55555555))
-    x = (x & _U32(0x33333333)) + ((x >> 2) & _U32(0x33333333))
-    x = (x + (x >> 4)) & _U32(0x0F0F0F0F)
-    return ((x * _U32(0x01010101)) >> 24).astype(jnp.int32)
 
 
 def _kernel(tx_ref, fi_ref, out_ref):
@@ -60,9 +55,9 @@ def _kernel(tx_ref, fi_ref, out_ref):
     tx = tx_ref[0]                                  # [BT, W]
     fi = fi_ref[...]                                # [BF, W]
     missing = fi[None, :, :] & ~tx[:, None, :]      # [BT, BF, W]
-    miss_ct = _popcount_swar(missing).sum(axis=-1)  # [BT, BF]
+    miss_ct = popcount(missing).sum(axis=-1)  # [BT, BF]
     contained = (miss_ct == 0).astype(jnp.int32)
-    out_ref[...] += contained.sum(axis=0)[None, :]
+    out_ref[...] += contained.sum(axis=0)[None, None, :]
 
 
 @functools.partial(
@@ -105,8 +100,10 @@ def block_itemset_supports_pallas(
             pl.BlockSpec((1, bt, Wp), lambda s, f, t: (s, t, 0)),
             pl.BlockSpec((bf, Wp), lambda s, f, t: (f, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bf), lambda s, f, t: (s, f)),
-        out_shape=jax.ShapeDtypeStruct((S, Fp), jnp.int32),
+        # S rides a leading axis: a (1, BF) block over S rows would break
+        # the sublane tiling, a (1, 1, BF) block over [S, 1, Fp] does not
+        out_specs=pl.BlockSpec((1, 1, bf), lambda s, f, t: (s, 0, f)),
+        out_shape=jax.ShapeDtypeStruct((S, 1, Fp), jnp.int32),
         interpret=interpret,
     )(tx, fi)
-    return out[:, :F]
+    return out[:, 0, :F]

@@ -19,10 +19,11 @@ steps — the pattern of ``pair_support.py``:
     3-D AND ``[BK, BI, BW]``; work per output element is W AND+popcount ops
     on 32-bit lanes.
   * ``multi_extension_supports_mxu_pallas``  — unpack both operands to 0/1
-    bf16 inside the kernel and feed the 128×128 MXU with
-    ``dot(prefixes, itemsᵀ)``: popcount(AND) ≡ dot of indicator vectors,
-    exact in f32 accumulation for supports < 2²⁴.  Preferable once K is large
-    enough to fill MXU rows (K ≳ 64); for small frontiers the VPU form wins.
+    bf16 inside the kernel, one bit plane at a time, and feed the 128×128
+    MXU with ``dot(prefixes, itemsᵀ)``: popcount(AND) ≡ dot of indicator
+    vectors, exact in f32 accumulation for supports < 2²⁴.  Preferable once
+    K is large enough to fill MXU rows (K ≳ 64); for small frontiers the VPU
+    form wins.
 """
 from __future__ import annotations
 
@@ -32,14 +33,32 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.bitmap import popcount_u32 as popcount
+
 _U32 = jnp.uint32
 
 
-def _popcount_swar(x):
-    x = x - ((x >> 1) & _U32(0x55555555))
-    x = (x & _U32(0x33333333)) + ((x >> 2) & _U32(0x33333333))
-    x = (x + (x >> 4)) & _U32(0x0F0F0F0F)
-    return ((x * _U32(0x01010101)) >> 24).astype(jnp.int32)
+def bitplane_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """f32[M, N] = Σ_w popcount(a[m, w] & b[n, w]) on the MXU.
+
+    popcount(AND) is the dot of 0/1 indicator vectors; the 32 bit planes of
+    each packed word are fed as 32 bf16 dots contracting over W, so no lane
+    reshape is needed.  Exact while counts < 2²⁴ (f32 accumulation).
+    """
+
+    def plane(words, s):
+        bit = ((words >> s) & _U32(1)).astype(jnp.int32)
+        return bit.astype(jnp.float32).astype(jnp.bfloat16)
+
+    def body(s, acc):
+        s = s.astype(_U32)
+        return acc + jax.lax.dot_general(
+            plane(a, s), plane(b, s), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    acc = jnp.zeros((a.shape[0], b.shape[0]), jnp.float32)
+    return jax.lax.fori_loop(0, 32, body, acc)
 
 
 def _vpu_kernel(tids_ref, items_ref, out_ref):
@@ -52,7 +71,7 @@ def _vpu_kernel(tids_ref, items_ref, out_ref):
     t = tids_ref[...]                               # [BK, BW]
     a = items_ref[...]                              # [BI, BW]
     inter = t[:, None, :] & a[None, :, :]           # [BK, BI, BW]
-    out_ref[...] += _popcount_swar(inter).sum(axis=-1)
+    out_ref[...] += popcount(inter).sum(axis=-1)
 
 
 @functools.partial(
@@ -104,19 +123,7 @@ def _mxu_kernel(tids_ref, items_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    def unpack(words):  # uint32[B, BW] -> bf16[B, BW*32] of 0/1
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-        bits = (words[:, :, None] >> shifts) & _U32(1)
-        return bits.reshape(words.shape[0], -1).astype(jnp.bfloat16)
-
-    t = unpack(tids_ref[...])                       # [BK, BW*32]
-    a = unpack(items_ref[...])                      # [BI, BW*32]
-    out_ref[...] += jax.lax.dot_general(
-        t,
-        a,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] += bitplane_dot(tids_ref[...], items_ref[...])
 
 
 @functools.partial(
@@ -128,7 +135,7 @@ def multi_extension_supports_mxu_pallas(
     *,
     block_k: int = 128,
     block_i: int = 128,
-    block_w: int = 64,   # 64 words = 2048 unpacked bf16 lanes per step
+    block_w: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """int32[K, I] via fused unpack+MXU-dot.  Exact for supports < 2^24."""
@@ -136,7 +143,7 @@ def multi_extension_supports_mxu_pallas(
     K = prefix_tids.shape[0]
     bk = min(block_k, max(8, K))
     bi = min(block_i, max(8, I))
-    bw = min(block_w, max(4, W))
+    bw = min(block_w, max(128, W))
     pk, pi, pw = (-K) % bk, (-I) % bi, (-W) % bw
     tids = jnp.pad(prefix_tids, ((0, pk), (0, pw)))
     items = jnp.pad(item_bits, ((0, pi), (0, pw)))

@@ -4,8 +4,9 @@ On TPU the Pallas kernels run compiled; on CPU (this container) the pure-jnp
 reference path is used for speed, with ``interpret=True`` Pallas execution
 available everywhere for validation (exercised by the kernel tests).
 
-``extension_supports`` is the function the Eclat/MFI miners take as their
-``support_fn`` plug-in.
+``support_fns`` gives the Eclat/MFI miners their ``support_fn`` and
+``multi_support_fn`` plug-ins; ``fimi.run`` and the cluster executor both
+mine through it.
 
 Every dispatch is wrapped by the kernel profiler
 (:mod:`repro.obs.profile`): when enabled, eager calls get device-synced
@@ -34,6 +35,20 @@ from repro.obs import profile as _prof
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+@functools.lru_cache(maxsize=None)
+def support_fns(force: str | None = None, use_mxu: bool = False):
+    """``(support_fn, multi_support_fn)`` miner plug-ins pinned to ``force``.
+
+    The miners take these as static jit arguments; caching gives each
+    ``(force, use_mxu)`` one stable identity, so repeated runs reuse their
+    compiled executables.
+    """
+    return (
+        partial(extension_supports, force=force),
+        partial(multi_extension_supports, use_mxu=use_mxu, force=force),
+    )
 
 
 def _profiled(family, dims_fn):

@@ -10,7 +10,8 @@ pattern (W is the minormost sequential grid axis):
   * ``pair_supports_pallas``      — VPU SWAR popcount over an AND of tiles.
     Work per output element: W AND+popcount ops on 32-bit lanes.
   * ``pair_supports_mxu_pallas``  — **beyond-paper TPU adaptation**: unpack the
-    packed words to 0/1 bf16 inside the kernel and feed the 128×128 MXU with
+    packed words to 0/1 bf16 inside the kernel (one bit plane per dot, see
+    ``multi_support.bitplane_dot``) and feed the 128×128 MXU with
     ``dot(bits, bitsᵀ)``.  popcount(AND) ≡ dot-product of indicator vectors,
     exact in f32 accumulation for supports < 2²⁴.  This turns a VPU-bound
     bit-twiddle into an MXU matmul at 32 MACs per packed word — the itemset-
@@ -24,14 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_U32 = jnp.uint32
-
-
-def _popcount_swar(x):
-    x = x - ((x >> 1) & _U32(0x55555555))
-    x = (x & _U32(0x33333333)) + ((x >> 2) & _U32(0x33333333))
-    x = (x + (x >> 4)) & _U32(0x0F0F0F0F)
-    return ((x * _U32(0x01010101)) >> 24).astype(jnp.int32)
+from repro.core.bitmap import popcount_u32 as popcount
+from repro.kernels.multi_support import bitplane_dot
 
 
 def _vpu_kernel(a_ref, b_ref, out_ref):
@@ -44,7 +39,7 @@ def _vpu_kernel(a_ref, b_ref, out_ref):
     a = a_ref[...]                                  # [BI, BW]
     b = b_ref[...]                                  # [BJ, BW]
     inter = a[:, None, :] & b[None, :, :]           # [BI, BJ, BW]
-    out_ref[...] += _popcount_swar(inter).sum(axis=-1)
+    out_ref[...] += popcount(inter).sum(axis=-1)
 
 
 @functools.partial(
@@ -54,12 +49,12 @@ def pair_supports_pallas(
     item_bits: jnp.ndarray,  # uint32[I, W]
     valid_tid: jnp.ndarray,  # uint32[W]
     *,
-    block_i: int = 64,
-    block_j: int = 64,
+    block_i: int = 8,
+    block_j: int = 128,
     block_w: int = 256,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """int32[I, I] via VPU popcount.  VMEM/step ≈ BI·BJ·BW·4 B (4 MiB def.)."""
+    """int32[I, I] via VPU popcount.  VMEM/step ≈ BI·BJ·BW·4 B (1 MiB def.)."""
     I, W = item_bits.shape
     bi, bj = min(block_i, max(8, I)), min(block_j, max(8, I))
     bw = min(block_w, max(128, W))
@@ -92,19 +87,7 @@ def _mxu_kernel(a_ref, b_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    def unpack(words):  # uint32[B, BW] -> bf16[B, BW*32] of 0/1
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-        bits = (words[:, :, None] >> shifts) & _U32(1)
-        return bits.reshape(words.shape[0], -1).astype(jnp.bfloat16)
-
-    a = unpack(a_ref[...])
-    b = unpack(b_ref[...])
-    out_ref[...] += jax.lax.dot_general(
-        a,
-        b,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] += bitplane_dot(a_ref[...], b_ref[...])
 
 
 @functools.partial(
@@ -116,13 +99,13 @@ def pair_supports_mxu_pallas(
     *,
     block_i: int = 128,
     block_j: int = 128,
-    block_w: int = 64,   # 64 words = 2048 unpacked bf16 lanes per step
+    block_w: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """int32[I, I] via fused unpack+MXU-dot.  Exact for supports < 2^24."""
     I, W = item_bits.shape
     bi, bj = min(block_i, max(8, I)), min(block_j, max(8, I))
-    bw = min(block_w, max(4, W))
+    bw = min(block_w, max(128, W))
     pi, pj, pw = (-I) % bi, (-I) % bj, (-W) % bw
     masked = item_bits & valid_tid[None, :]
     a = jnp.pad(masked, ((0, pi), (0, pw)))

@@ -2,9 +2,13 @@
 
 These are thin named wrappers over ``repro.core.bitmap`` reference forms so the
 kernel tests have a single import point, plus the unpacked-MXU reference.
+The two all-pairs sweeps are jitted: run op by op they would hold their
+``[..., F, IW]`` broadcast in device memory (gigabytes at serving and
+streaming widths); fused, only the counts are.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import bitmap as bm
@@ -41,6 +45,7 @@ def pair_supports_mxu_ref(item_bits: jnp.ndarray, valid_tid: jnp.ndarray) -> jnp
     return jnp.dot(masked, masked.T).astype(jnp.int32)
 
 
+@jax.jit
 def subset_superset_counts_ref(
     query_masks: jnp.ndarray, fi_masks: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -57,6 +62,7 @@ def subset_superset_counts_ref(
     )
 
 
+@jax.jit
 def block_itemset_supports_ref(
     tx_blocks: jnp.ndarray, fi_masks: jnp.ndarray
 ) -> jnp.ndarray:

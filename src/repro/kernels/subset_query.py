@@ -23,8 +23,9 @@ Grid ``(Q/BQ, F/BF, W/BW)`` with W minormost (sequential on TPU) so both
 int32 accumulators live in their output blocks across W steps — the pattern
 of ``multi_support.py``/``pair_support.py``.  Unlike those kernels the
 reduced axis here is the *item-word* axis (IW = n_words(n_items), a few
-words), not the transaction-word axis, so W is typically a single step and
-the default ``block_w`` is small; Q and F carry the parallelism.
+words), not the transaction-word axis, so by default the word block is the
+whole of IW (padded to a lane multiple past 128 words) and W is a single
+step; Q and F carry the parallelism.
 """
 from __future__ import annotations
 
@@ -35,14 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_U32 = jnp.uint32
-
-
-def _popcount_swar(x):
-    x = x - ((x >> 1) & _U32(0x55555555))
-    x = (x & _U32(0x33333333)) + ((x >> 2) & _U32(0x33333333))
-    x = (x + (x >> 4)) & _U32(0x0F0F0F0F)
-    return ((x * _U32(0x01010101)) >> 24).astype(jnp.int32)
+from repro.core.bitmap import popcount_u32 as popcount
 
 
 def _kernel(query_ref, fi_ref, miss_ref, extra_ref):
@@ -57,8 +51,8 @@ def _kernel(query_ref, fi_ref, miss_ref, extra_ref):
     f = fi_ref[...]                                 # [BF, BW]
     only_f = f[None, :, :] & ~q[:, None, :]         # [BQ, BF, BW]
     only_q = q[:, None, :] & ~f[None, :, :]
-    miss_ref[...] += _popcount_swar(only_f).sum(axis=-1)
-    extra_ref[...] += _popcount_swar(only_q).sum(axis=-1)
+    miss_ref[...] += popcount(only_f).sum(axis=-1)
+    extra_ref[...] += popcount(only_q).sum(axis=-1)
 
 
 @functools.partial(
@@ -70,21 +64,21 @@ def subset_superset_counts_pallas(
     *,
     block_q: int = 128,
     block_f: int = 128,
-    block_w: int = 8,
+    block_w: int | None = None,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(miss, extra)`` int32[Q, F] set-difference popcount matrices.
 
     Pads Q, F, W to tile multiples (zero words change no counts; padded
-    rows are sliced off).  VMEM per step ≈ 2·BQ·BF·BW·4 B for the widened
-    ANDNs (1 MiB at defaults).
+    rows are sliced off).  ``block_w=None`` takes the whole word axis.
+    VMEM per step ≈ 2·BQ·BF·BW·4 B for the widened ANDNs.
     """
     Q, W = query_masks.shape
     F = fi_masks.shape[0]
     assert fi_masks.shape[1] == W, "query/index word width mismatch"
     bq = min(block_q, max(8, Q))
     bf = min(block_f, max(8, F))
-    bw = min(block_w, W)
+    bw = min(block_w, W) if block_w else (W if W <= 128 else -(-W // 128) * 128)
     pq, pf, pw = (-Q) % bq, (-F) % bf, (-W) % bw
     q = jnp.pad(query_masks, ((0, pq), (0, pw)))
     f = jnp.pad(fi_masks, ((0, pf), (0, pw)))

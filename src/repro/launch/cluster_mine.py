@@ -27,7 +27,7 @@ from __future__ import annotations
 import argparse
 import sys
 
-from repro.launch.host_devices import preparse_devices
+from repro.launch.host_devices import DEVICES_HELP, preparse_devices
 
 preparse_devices()  # must run before anything imports jax
 
@@ -144,6 +144,7 @@ def main():
 
     from repro import cluster
     from repro.core import eclat, fimi
+    from repro.launch import compile_cache
     from repro.launch.data_source import resolve_source
     from repro.obs.session import add_obs_flags, start_session
 
@@ -158,8 +159,7 @@ def main():
                     help="store block size (rows) when spilling/ingesting")
     ap.add_argument("--support", type=float, default=0.1)
     ap.add_argument("-P", type=int, default=4)
-    ap.add_argument("--devices", type=int, default=0,
-                    help="fork N simulated host devices (before jax init)")
+    ap.add_argument("--devices", type=int, default=0, help=DEVICES_HELP)
     ap.add_argument("--scheduler", default="auto",
                     choices=["auto", "lpt", "repl_min"])
     ap.add_argument("--alpha", type=float, default=0.5)
@@ -190,6 +190,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     add_obs_flags(ap)
     args = ap.parse_args()
+    compile_cache.enable()
     obs = start_session(args, "cluster_mine")
 
     store, dense, src = resolve_source(

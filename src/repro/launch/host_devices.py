@@ -1,5 +1,7 @@
 """``--devices N`` preamble shared by the CLI launchers.
 
+The flag only forks virtual *CPU* devices (XLA's host platform device
+count): on a TPU it changes nothing, and the mesh is the chips present.
 XLA locks the host device count at first backend initialization, so the
 flag must be applied to ``XLA_FLAGS`` *before anything imports jax* — the
 launchers call :func:`preparse_devices` at module import, ahead of their
@@ -10,6 +12,12 @@ from __future__ import annotations
 import os
 import sys
 from typing import Optional, Sequence
+
+#: ``--help`` text of the launchers' ``--devices`` flag.
+DEVICES_HELP = (
+    "fork N virtual CPU devices before jax starts (CPU only: the flag "
+    "changes nothing on a TPU, where the chips present are the devices)"
+)
 
 
 def preparse_devices(argv: Optional[Sequence[str]] = None) -> Optional[int]:

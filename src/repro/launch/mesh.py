@@ -10,13 +10,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions: older releases have neither
-    ``axis_types`` nor ``jax.sharding.AxisType``; Auto is their default."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -31,7 +31,7 @@ from __future__ import annotations
 import argparse
 import sys
 
-from repro.launch.host_devices import preparse_devices
+from repro.launch.host_devices import DEVICES_HELP, preparse_devices
 
 preparse_devices()  # must run before anything imports jax
 
@@ -42,6 +42,7 @@ def main():
     import jax
 
     from repro.core import eclat, fimi
+    from repro.launch import compile_cache
     from repro.launch.data_source import resolve_source
     from repro.launch.mesh import make_miner_mesh
     from repro.obs.session import add_obs_flags, start_session
@@ -64,7 +65,7 @@ def main():
     ap.add_argument("--variant", default="reservoir",
                     choices=["seq", "par", "reservoir"])
     ap.add_argument("-P", type=int, default=4)
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0, help=DEVICES_HELP)
     ap.add_argument("--scheduler", default="lpt", choices=["lpt", "repl_min"])
     ap.add_argument("--alpha", type=float, default=0.5)
     ap.add_argument("--seed", type=int, default=0)
@@ -72,6 +73,7 @@ def main():
                     help="DFS nodes mined per while_loop trip (K)")
     add_obs_flags(ap)
     args = ap.parse_args()
+    compile_cache.enable()
     obs = start_session(args, "mine")
 
     # ---- resolve the data source -------------------------------------------

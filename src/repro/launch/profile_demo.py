@@ -32,6 +32,7 @@ def main():
     from repro.core import eclat, fimi
     from repro.data.ibm_gen import generate_dense, params_from_name
     from repro.kernels import ops
+    from repro.launch import compile_cache
     from repro.obs import profile as obs_profile
     from repro.obs.session import add_obs_flags, start_session
 
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     add_obs_flags(ap)
     args = ap.parse_args()
+    compile_cache.enable()
     args.profile = True      # this driver exists to profile
     obs = start_session(args, "profile_demo")
     prof = obs_profile.profiler()

@@ -292,6 +292,7 @@ def merge_bench(path: str, keys: dict) -> None:
 def main(argv: Optional[List[str]] = None) -> int:
     from repro.core import eclat
     from repro.data.ibm_gen import generate_dense, params_from_name
+    from repro.launch import compile_cache
     from repro.obs import trace as obs_trace
     from repro.obs.session import add_obs_flags, start_session
     from repro.obs.slo import SLOPolicy, SLOTracker
@@ -355,6 +356,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     add_obs_flags(ap)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     obs = start_session(args, "serve_load")
 
     # ---- index --------------------------------------------------------------
@@ -521,6 +523,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                   alerts=len(measure_alerts))
         obs.finish(**{k: v for k, v in slo_keys.items()})
 
+    if st["errors"]:
+        print(f"SERVE FAILED: {st['errors']} request(s) ended in error",
+              file=sys.stderr)
+        return 1
     if args.gate and violated:
         why = []
         if measure_alerts:

@@ -18,8 +18,9 @@ program per query kind for the whole session).
 from __future__ import annotations
 
 import argparse
+import sys
 
-from repro.launch.host_devices import preparse_devices
+from repro.launch.host_devices import DEVICES_HELP, preparse_devices
 
 preparse_devices()  # must run before anything imports jax
 
@@ -116,11 +117,12 @@ def replay(stream, engine, cache, batch):
     return latencies, n_dispatched
 
 
-def main():
+def main(argv=None) -> int:
     import jax
 
     from repro.core import eclat, fimi
     from repro.data.ibm_gen import generate_dense, params_from_name
+    from repro.launch import compile_cache
     from repro.launch.mesh import make_miner_mesh
     from repro.obs.session import add_obs_flags, start_session
     from repro.serve import QueryCache, QueryEngine
@@ -132,7 +134,7 @@ def main():
     ap.add_argument("--variant", default="reservoir",
                     choices=["seq", "par", "reservoir"])
     ap.add_argument("-P", type=int, default=4)
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0, help=DEVICES_HELP)
     ap.add_argument("--frontier", type=int, default=16,
                     help="DFS nodes mined per while_loop trip (K)")
     ap.add_argument("--queries", type=int, default=1024)
@@ -146,7 +148,8 @@ def main():
                     help="distinct queries per kind in the workload")
     ap.add_argument("--seed", type=int, default=0)
     add_obs_flags(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
     obs = start_session(args, "serve_mine")
 
     # ---- mine ---------------------------------------------------------------
@@ -230,7 +233,8 @@ def main():
     from repro.core.rules import format_rule
     for r in range(min(5, rule_index.n_rules)):
         print("  " + format_rule(rule_index.rule(r), n_tx))
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -77,6 +77,7 @@ def serve_block(sm, rng, n_queries, zipf_a=1.3):
 
 def main():
     from repro.core import eclat, fimi
+    from repro.launch import compile_cache
     from repro.data.ibm_gen import drifting_stream, params_from_name
     from repro.obs.session import add_obs_flags, start_session
     from repro.stream import StreamingMiner, StreamParams, fimi_mine_fn
@@ -123,6 +124,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     add_obs_flags(ap)
     args = ap.parse_args()
+    compile_cache.enable()
     obs = start_session(args, "stream_mine")
 
     gen_params = params_from_name(args.db, seed=args.seed)
@@ -146,6 +148,7 @@ def main():
                     max_out=1 << 15, max_stack=8192,
                     frontier_size=args.frontier,
                 ),
+                force=args.force,
             ),
             seed=args.seed,
         )
@@ -159,6 +162,7 @@ def main():
                     max_out=1 << 15, max_stack=8192,
                     frontier_size=args.frontier,
                 ),
+                force=args.force,
             ),
             seed=args.seed,
         )

@@ -32,7 +32,7 @@ jax-free report CLI.  See DESIGN.md, "Observability".
 """
 from repro.obs.critpath import SpanDag, critical_path  # noqa: F401
 from repro.obs.doctor import Finding, Thresholds, diagnose  # noqa: F401
-from repro.obs.machine import MachineModel, machine_for_backend  # noqa: F401
+from repro.obs.machine import MachineModel, machine_for_device_kind  # noqa: F401
 from repro.obs.metrics import (  # noqa: F401
     Counter,
     Gauge,

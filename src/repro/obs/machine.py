@@ -11,7 +11,7 @@ from these numbers in contexts where jax never loads.
 Two units of "flops" coexist deliberately:
 
   * the LLM roofline prices bf16 MXU FLOPs (``peak_flops`` of ``TPU_V5E``
-    is the 197 TFLOP/s bf16 figure from the brief);
+    is the published 197 TFLOP/s bf16 figure);
   * the mining kernels are integer word machines — one "op" is one 32-bit
     word operation (AND / popcount / add).  ``word_ops_peak`` is the
     sustained word-op throughput the kernels can reach on that target
@@ -44,22 +44,24 @@ class MachineModel:
         return self.word_ops_peak / self.hbm_bw
 
 
-#: TPU v5e, from the brief: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s ICI.
-#: Word-op peak: 8 VPU lanes × 128 sublanes × ~3 ops/cycle @ ~0.9 GHz is
-#: O(1e12); we use a conservative 1e12 sustained.
+#: TPU v5e (``device_kind`` "TPU v5 lite").  Peaks from the Google Cloud
+#: documentation page "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+#: inter-chip interconnect (= 200e9 B/s).  ``word_ops_peak`` is an estimate,
+#: not a published figure: 8 sublanes × 128 lanes × ~1 op/cycle per VALU
+#: slot at ~1.5 GHz is O(1e12); no chip run has measured it yet.
 TPU_V5E = MachineModel(
     name="tpu-v5e",
     peak_flops=197e12,
     hbm_bw=819e9,
-    link_bw=50e9,
+    link_bw=1600e9 / 8,
     word_ops_peak=1e12,
 )
 
-#: A container-class x86 host (the CI target): XLA:CPU multithreaded.
-#: ~50 G sustained 32-bit vector word-ops/s and ~20 GB/s effective stream
-#: bandwidth are deliberately round numbers — the profiler's verdicts
-#: compare *terms against each other*, so only their ratio (the balance,
-#: 2.5 ops/byte) needs to be in the right regime.
+#: A container-class x86 host (``device_kind`` "cpu"; the test target):
+#: XLA:CPU multithreaded.  ~50 G sustained 32-bit vector word-ops/s and
+#: ~20 GB/s effective stream bandwidth are deliberately round estimates —
+#: the profiler's verdicts compare *terms against each other*, so only their
+#: ratio (the balance, 2.5 ops/byte) needs to be in the right regime.
 CPU_HOST = MachineModel(
     name="cpu-host",
     peak_flops=2e11,
@@ -68,9 +70,18 @@ CPU_HOST = MachineModel(
     word_ops_peak=5e10,
 )
 
+#: Every device this repository prices work on, keyed by the
+#: ``device_kind`` jax reports.  A device missing here is an error.
+MACHINES = {"TPU v5 lite": TPU_V5E, "cpu": CPU_HOST}
 
-def machine_for_backend(backend: str | None) -> MachineModel:
-    """The model to price kernels against on a given jax backend name."""
-    if backend and backend.lower() in ("tpu",):
-        return TPU_V5E
-    return CPU_HOST
+
+def machine_for_device_kind(device_kind: str) -> MachineModel:
+    """The model to price kernels against on a ``device_kind``; raises for a
+    device that has no entry rather than pricing it as another."""
+    try:
+        return MACHINES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no machine model for device_kind {device_kind!r}; "
+            f"known: {sorted(MACHINES)}"
+        ) from None

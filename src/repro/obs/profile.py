@@ -59,7 +59,7 @@ import time
 from typing import Callable, Dict, Optional, Tuple
 
 from repro.obs import metrics as obs_metrics
-from repro.obs.machine import CPU_HOST, MachineModel, machine_for_backend
+from repro.obs.machine import MachineModel, machine_for_device_kind
 
 #: The five dispatch families of ``repro.kernels.ops``.
 FAMILIES = ("bitmap", "multi", "pair", "subset", "delta")
@@ -142,7 +142,7 @@ class KernelProfiler:
 
     def __init__(self, machine: Optional[MachineModel] = None):
         self.enabled = False          # read directly by the ops wrapper
-        self._machine = machine       # None → resolve from backend lazily
+        self._machine = machine       # None → by device_kind, lazily
         self._buckets: Dict[Tuple[str, str], _Bucket] = {}
         self._traced: Dict[str, int] = {}   # family -> trace-time dispatches
         self._lock = threading.Lock()
@@ -164,12 +164,9 @@ class KernelProfiler:
     @property
     def machine(self) -> MachineModel:
         if self._machine is None:
-            try:
-                import jax
+            import jax
 
-                self._machine = machine_for_backend(jax.default_backend())
-            except Exception:
-                self._machine = CPU_HOST
+            self._machine = machine_for_device_kind(jax.devices()[0].device_kind)
         return self._machine
 
     # -- recording -----------------------------------------------------------

@@ -256,12 +256,27 @@ def to_device_shards(
     """``uint32[P, T, IW]`` horizontal shards, bit-exact with
     ``fimi.shard_db(store.to_dense(), P)`` (row order preserved, the last
     ``n_tx mod P`` rows dropped) — but assembled block-by-block so the host
-    never holds more than the reader's budget."""
+    never holds more than the reader's budget.  The result sits on the
+    default device; :func:`place_on_mesh` spreads it over a miner mesh."""
     T = store.n_tx // P
     rows = to_device_rows(
         store, T * P, host_budget_blocks=host_budget_blocks, reader=reader
     )
     return rows.reshape(P, T, store.n_words)
+
+
+def place_on_mesh(shards: jnp.ndarray, mesh) -> jnp.ndarray:
+    """Put ``[P, ...]`` shards one per device of a 1-D miner mesh.
+
+    A ``NamedSharding`` over the mesh axis: miner p's shard lives on mesh
+    device p, so ``shard_map`` phases read it in place instead of moving it
+    off the default device on every call.  A no-op when already so placed.
+    """
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    return jax.device_put(
+        shards, NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -58,7 +58,8 @@ class StreamParams:
     batch: int = 256                # QueryEngine dispatch width
     top_k: int = 5
     cache_capacity: int = 2048
-    force: Optional[str] = None     # kernel backend pin (kernels.ops)
+    force: Optional[str] = None     # kernel backend pin (kernels.ops),
+    #                                 re-mines included
     spill_dir: Optional[str] = None  # persist expired blocks to a TxStore
     seed: int = 0
 
@@ -105,14 +106,15 @@ class StreamStats:
 
 
 def fimi_mine_fn(
-    P: int = 4, fimi_params=None, seed: int = 0
+    P: int = 4, fimi_params=None, seed: int = 0, force: Optional[str] = None
 ) -> MineFn:
     """Default re-miner: the full Parallel-FIMI pipeline over the window.
 
     Shards the materialized window row-wise over ``P`` (virtual) miners and
     runs the four-phase pipeline (``core.fimi.run``) with ``materialize=True``.
     ``fimi_params`` overrides everything except ``min_support_rel``, which is
-    always derived from the trigger's absolute minsup.
+    always derived from the trigger's absolute minsup; ``force`` pins the
+    default params' kernels.
     """
     from repro.core import eclat, fimi
 
@@ -127,6 +129,7 @@ def fimi_mine_fn(
             eclat=eclat.EclatConfig(
                 max_out=1 << 14, max_stack=4096, frontier_size=16
             ),
+            force=force,
         )
         # (abs−0.5)/n_tx survives the float round-trip: fimi.run's
         # ceil(rel·n_tx) lands exactly on abs_minsup, whereas abs/n_tx can
@@ -175,7 +178,9 @@ class StreamingMiner:
             border_hysteresis=params.border_hysteresis,
             seed=params.seed,
         )
-        self.mine_fn = mine_fn or fimi_mine_fn(seed=params.seed)
+        self.mine_fn = mine_fn or fimi_mine_fn(
+            seed=params.seed, force=params.force
+        )
         # store-backed spill: evicted blocks persist as the stream's history
         self.spill: Optional[WindowSpill] = (
             WindowSpill(params.spill_dir, params.block_tx, n_items)

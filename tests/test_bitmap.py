@@ -2,7 +2,8 @@
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st  # skips @given tests w/o hypothesis
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax.numpy as jnp
 

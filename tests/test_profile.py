@@ -13,7 +13,7 @@ from repro.core import bitmap as bm
 from repro.kernels import ops
 from repro.obs import metrics as obs_metrics
 from repro.obs import profile as obs_profile
-from repro.obs.machine import CPU_HOST, TPU_V5E, machine_for_backend
+from repro.obs.machine import CPU_HOST, TPU_V5E, machine_for_device_kind
 
 
 @pytest.fixture(autouse=True)
@@ -145,8 +145,15 @@ def test_shape_buckets_round_up_to_pow2():
 
 
 def test_machine_for_backend():
-    assert machine_for_backend("tpu") is TPU_V5E
-    assert machine_for_backend("cpu") is CPU_HOST
+    assert machine_for_device_kind("TPU v5 lite") is TPU_V5E
+    assert machine_for_device_kind("cpu") is CPU_HOST
+    assert TPU_V5E.link_bw == 200e9   # 1,600 Gbit/s published ICI
+    for unknown in ("TPU v4", "TPU v6 lite", "gpu"):
+        with pytest.raises(ValueError, match="no machine model"):
+            machine_for_device_kind(unknown)
+    # the profiler prices the device this process runs on, by its kind
+    assert obs_profile.KernelProfiler().machine is machine_for_device_kind(
+        jax.devices()[0].device_kind)
     assert TPU_V5E.balance_word_ops_per_byte > CPU_HOST.balance_word_ops_per_byte / 10
 
 

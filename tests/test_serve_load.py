@@ -109,3 +109,37 @@ def test_merge_bench_preserves_existing_keys(tmp_path):
     p2 = tmp_path / "new.json"
     serve_load.merge_bench(str(p2), {"slo_qps": 1.0})
     assert json.loads(p2.read_text())["bench"] == "serve"
+
+
+def test_request_errors_exit_nonzero_without_gate(tmp_path, capsys,
+                                                  monkeypatch):
+    """A failed sweep ends its requests in error; the harness must not
+    report success for them, gate or no gate."""
+    from repro.serve.service import MiningService
+
+    def broken(self, snap, kind, masks):
+        raise RuntimeError("injected dispatch failure")
+
+    monkeypatch.setattr(MiningService, "_dispatch", broken)
+    argv = [a for a in _argv(tmp_path, **{"--duration": "0.5",
+                                          "--ramp": "0"})
+            if a != "--gate"]
+    rc = serve_load.main(argv)
+    cap = capsys.readouterr()
+    assert rc == 1, cap.out
+    assert "request(s) ended in error" in cap.err
+
+
+def test_serve_mine_fails_on_dispatch_error(monkeypatch):
+    """serve_mine answers through the engine directly: a failing dispatch
+    must end the run with an error, never a clean exit."""
+    from repro.launch import serve_mine
+    from repro.serve import QueryEngine
+
+    def broken(self, masks, **kw):
+        raise RuntimeError("injected dispatch failure")
+
+    monkeypatch.setattr(QueryEngine, "support", broken)
+    with pytest.raises(RuntimeError, match="injected dispatch failure"):
+        serve_mine.main(["--db", DB, "--support", "0.1", "-P", "2",
+                         "--queries", "64", "--batch", "32"])

@@ -1,0 +1,90 @@
+"""Every ``kernels/ops`` family compiles for a TPU v5e, with no chip attached.
+
+Each test lowers one dispatch with ``force="pallas"`` and compiles it for a
+described ``v5e:2x2`` topology at the widths ``chip_smoke.py`` runs: 1,000
+items, the 100,000-transaction Phase-4 slab (3,125 tid words) and K=16
+frontier nodes; serving at Q=256 queries over 32-word masks; streaming at
+S=2 blocks of 4,096 rows.  The compiler refuses unaligned tiles and VMEM
+overuse here exactly as on the chip.  The topology is described inside a
+fixture, never at import: only one process may load the TPU library.
+"""
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.launch import compile_cache
+
+I, W, K, P = 1000, 3125, 16, 4          # items, slab tid words, frontier, miners
+W_SAMPLE = 64                           # Thm 6.1 sample: 2,048 tx
+Q, F, IW = 256, 4096, 32                # serving batch, index rows, mask words
+T = 4096                                # rows per arrive/expire block
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # AOT compiles for a described chip cannot be read back from the
+    # persistent cache; keep them out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.uint32, sharding=sharding)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+FAMILIES = {
+    "bitmap": (partial(ops.extension_supports, force="pallas"),
+               [(I, W_SAMPLE), (W_SAMPLE,)]),
+    "multi_vpu": (partial(ops.multi_extension_supports, force="pallas"),
+                  [(I, W), (K, W)]),
+    "multi_mxu": (partial(ops.multi_extension_supports, force="pallas",
+                          use_mxu=True),
+                  [(I, W), (K, W)]),
+    "pair_vpu": (partial(ops.pair_supports, force="pallas", use_mxu=False),
+                 [(I, W_SAMPLE), (W_SAMPLE,)]),
+    "pair_mxu": (partial(ops.pair_supports, force="pallas"),
+                 [(I, W_SAMPLE), (W_SAMPLE,)]),
+    "subset": (partial(ops.subset_superset_counts, force="pallas"),
+               [(Q, IW), (F, IW)]),
+    "delta": (partial(ops.delta_supports, force="pallas"),
+              [(T, IW), (T, IW), (F, IW)]),
+    # P miners on one chip: the kernel under vmap, as Phase 4 runs it
+    "multi_vpu_vmap": (
+        jax.vmap(partial(ops.multi_extension_supports, force="pallas")),
+        [(P, I, W), (P, K, W)]),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_kernel_compiles_for_v5e(one_chip, family):
+    fn, shapes = FAMILIES[family]
+    compiled = _compile(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compile_cache_dir_env_wins(monkeypatch, tmp_path):
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path / "cache"))
+    assert compile_cache.cache_dir() == str(tmp_path / "cache")
+    monkeypatch.delenv(compile_cache.ENV)
+    fixed = compile_cache.cache_dir()
+    assert fixed == str(compile_cache.CHECKOUT_CACHE)
+    assert fixed.endswith("/.jax_cache")
+    assert (compile_cache.CHECKOUT_CACHE.parent / "chip_smoke.py").exists()
+    assert fixed == compile_cache.cache_dir()   # fixed: no pid, temp or time
