@@ -97,7 +97,8 @@ def _lift_support_fn(support_fn: SupportFn) -> MultiSupportFn:
 
 
 def _reservoir_update(state, itemsets_packed, supports, emit_mask, R):
-    """Algorithm R over the ≤I itemsets emitted this node (sequential fori)."""
+    """Algorithm R over the F·I itemset slots of this trip (sequential fori;
+    one step per slot, whether or not it emits)."""
 
     def body(i, carry):
         res_items, res_supp, seen, key = carry
@@ -116,12 +117,14 @@ def _reservoir_update(state, itemsets_packed, supports, emit_mask, R):
 
         return jax.lax.cond(emit_mask[i], do, lambda c: c, carry)
 
-    return jax.lax.fori_loop(0, emit_mask.shape[0], body, state)
+    with jax.named_scope("fimi/phase1/reservoir"):
+        return jax.lax.fori_loop(0, emit_mask.shape[0], body, state)
 
 
 @partial(
     jax.jit,
-    static_argnames=("config", "n_items", "support_fn", "multi_support_fn"),
+    static_argnames=("config", "n_items", "support_fn", "multi_support_fn",
+                     "scope"),
 )
 def mine_seeded(
     item_bits: jnp.ndarray,
@@ -136,6 +139,7 @@ def mine_seeded(
     n_items: int,
     support_fn: Optional[SupportFn] = None,
     multi_support_fn: Optional[MultiSupportFn] = None,
+    scope: str = "eclat_loop",
 ) -> EclatResult:
     """Mine all FIs in the union of K PBECs ``[prefix_k | ext_k]``.
 
@@ -149,7 +153,8 @@ def mine_seeded(
     nodes: one fused multi-prefix support sweep, one vectorized child scatter.
     ``multi_support_fn`` (if given) computes the fused ``[F, I]`` supports;
     otherwise a provided single-prefix ``support_fn`` is vmapped over the
-    frontier, falling back to the pure-jnp oracle.
+    frontier, falling back to the pure-jnp oracle.  The loop runs under
+    ``jax.named_scope(scope)``, which names it in the profiler's op metadata.
     """
     if multi_support_fn is None:
         if support_fn is not None:
@@ -298,7 +303,8 @@ def mine_seeded(
             popped=s.popped + active.sum().astype(jnp.int32),
         )
 
-    final = jax.lax.while_loop(cond, body, init)
+    with jax.named_scope(scope):
+        final = jax.lax.while_loop(cond, body, init)
     return EclatResult(
         items=final.out_items,
         supports=final.out_supp,

@@ -14,6 +14,7 @@ does between collectives.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from functools import partial
 from typing import Dict, List, Optional, Tuple
@@ -109,6 +110,9 @@ def shard_map_spmd(fn, P: int, mesh):
 # Driver
 # ---------------------------------------------------------------------------
 
+#: process-wide count of ``run`` calls: the ``mine`` arg of its spans
+_MINES = itertools.count(1)
+
 
 def run(
     tx_shards,                # uint32[P, T, IW] shards — or a store.TxStore
@@ -123,6 +127,20 @@ def run(
     host_budget_blocks: int = 2,
     reader=None,
 ) -> FimiResult:
+    """Mine every FI of ``tx_shards`` with P miners (Phases 1–4).
+
+    While tracing, the whole mine is span ``fimi/run`` (its self time is the
+    host glue between phases), and it and every ``fimi/*`` span inside carry
+    ``mine=<n>``, this call's number in the process.
+    """
+    mine = next(_MINES)
+    with obs_trace.TRACER.span("fimi/run", mine=mine):
+        return _run(tx_shards, n_items, params, key, spmd, mesh, materialize,
+                    P, host_budget_blocks, reader, mine)
+
+
+def _run(tx_shards, n_items, params, key, spmd, mesh, materialize, P,
+         host_budget_blocks, reader, mine) -> FimiResult:
     tr = obs_trace.TRACER
     if not hasattr(tx_shards, "shape"):   # a TxStore: mine out-of-core
         from repro.store import reader as store_reader
@@ -138,7 +156,7 @@ def run(
         # included) matches the in-memory path bit for bit.  Drivers pass
         # ``reader`` (a BlockReader on this store) to observe the streamed
         # host high-water mark of this very pass.
-        with tr.span("fimi/assemble_store", P=P):
+        with tr.span("fimi/assemble_store", P=P, mine=mine):
             tx_shards = tr.sync(store_reader.to_device_shards(
                 tx_shards, P, host_budget_blocks=host_budget_blocks,
                 reader=reader,
@@ -183,8 +201,15 @@ def run(
     minsup_rel = jnp.broadcast_to(
         jnp.asarray(params.min_support_rel, jnp.float32), (P,)
     )
-    with tr.span("fimi/phase1_sample", P=P, variant=params.variant):
+    K = max(1, min(params.eclat.frontier_size, params.eclat.max_stack))
+    with tr.span("fimi/phase1_sample", P=P, variant=params.variant,
+                 mine=mine) as sp:
         out1 = tr.sync(spmd(p1, P, mesh)(tx_shards, keys, minsup_rel))
+        if tr.enabled:
+            iters, popped, offers = jax.device_get(
+                (out1.n_iters, out1.n_popped, out1.fi_count))
+            sp.set(trips=int(np.max(iters)), popped=int(np.sum(popped)),
+                   offers=int(np.sum(offers)), K=K, I=n_items)
 
     sample_db_rows = np.asarray(jax.device_get(out1.sample_db))[0]  # replicated
     n_samp = sample_db_rows.shape[0]
@@ -242,11 +267,17 @@ def run(
     sample_masks = sample_masks[sample_masks.sum(axis=1) >= 2]
 
     # ---------------- Phase 2 ------------------------------------------------
-    def ext_supports(prefix: np.ndarray) -> np.ndarray:
-        tid = bm.tidlist_of_itemset(sample_bitdb, jnp.asarray(prefix))
-        return np.asarray(support_fn(sample_bitdb.item_bits, tid))
+    probes = 0
 
-    with tr.span("fimi/phase2_partition", scheduler=params.scheduler):
+    def ext_supports(prefix: np.ndarray) -> np.ndarray:
+        nonlocal probes
+        probes += 1
+        with tr.span("fimi/phase2_probe", mine=mine):
+            tid = bm.tidlist_of_itemset(sample_bitdb, jnp.asarray(prefix))
+            return np.asarray(support_fn(sample_bitdb.item_bits, tid))
+
+    with tr.span("fimi/phase2_partition", scheduler=params.scheduler,
+                 mine=mine) as sp:
         classes = pbec.partition(
             sample_masks,
             P,
@@ -276,6 +307,7 @@ def run(
         else:
             assignment = schedule.lpt_schedule(sizes, P)
         est_loads = schedule.loads_of(sizes, assignment, P)
+        sp.set(probes=probes)
 
     # ---------------- Phase 3 ------------------------------------------------
     C = len(classes)
@@ -288,7 +320,7 @@ def run(
     )
     class_valid_b = jnp.ones((P, C), jnp.bool_)
     class_assign_b = jnp.broadcast_to(jnp.asarray(assignment, jnp.int32), (P, C))
-    with tr.span("fimi/phase3_exchange", C=C):
+    with tr.span("fimi/phase3_exchange", C=C, mine=mine):
         out3 = tr.sync(spmd(p3, P, mesh)(
             tx_shards, local_valid, class_prefix_b, class_valid_b,
             class_assign_b,
@@ -323,7 +355,7 @@ def run(
     progress = obs_progress.ProgressEstimator(est_loads)
     progress.start()
     mine_t0 = time.perf_counter()
-    with tr.span("fimi/phase4_mine", Cmax=Cmax, A=A):
+    with tr.span("fimi/phase4_mine", Cmax=Cmax, A=A, mine=mine) as sp:
         out4 = spmd(p4, P, mesh)(
             slab,
             out3.slab_valid.reshape(P, -1),
@@ -337,6 +369,11 @@ def run(
             keys4,
         )
         out4 = jax.block_until_ready(out4)
+        if tr.enabled:
+            iters, popped = jax.device_get(
+                (out4.work_iters, out4.nodes_popped))
+            sp.set(trips=int(np.max(iters)), popped=int(np.sum(popped)),
+                   P=P, K=K)
     mine_s = time.perf_counter() - mine_t0
     trips_arr = np.asarray(out4.work_iters).astype(np.float64).reshape(-1)
     # Loop-attributed kernel work: the multi-support sweep executes inside
@@ -346,7 +383,7 @@ def run(
         obs_profile.PROFILER.observe_loop(
             "multi",
             {
-                "K": max(1, int(params.eclat.frontier_size)),
+                "K": K,
                 "I": n_items,
                 "W": (int(slab.shape[1]) + 31) // 32,
             },
@@ -377,46 +414,21 @@ def run(
         nodes_popped=np.asarray(out4.nodes_popped).reshape(-1),
         progress=final_progress,
     )
-    _emit_run_metrics(result, params, P)
+    _emit_run_metrics(result)
     if materialize:
         result.fi_dict = materialize_fis(result, n_items, abs_minsup)
     return result
 
 
-def _emit_run_metrics(result: FimiResult, params: FimiParams, P: int) -> None:
-    """Publish one pipeline pass into the process-global metrics registry.
-
-    Emits the estimated-vs-observed load story the thesis' Phase 2 is judged
-    by: per-shard estimated share (PBEC sizes via the scheduler) next to the
-    observed DFS-trip share, their max absolute gap as a gauge, and the
-    frontier occupancy (nodes actually popped per trip slot) as a histogram.
-    """
+def _emit_run_metrics(result: FimiResult) -> None:
+    """Publish one pipeline pass into the process-global metrics registry."""
     reg = obs_metrics.registry()
-    trips = result.work_iters.astype(np.float64).reshape(-1)
-    est = result.est_loads.astype(np.float64).reshape(-1)
     reg.counter("fimi/runs").inc()
-    reg.counter("fimi/trips").inc(int(trips.sum()))
+    reg.counter("fimi/trips").inc(int(result.work_iters.sum()))
     reg.counter("fimi/exchange_overflow").inc(result.exchange_overflow)
     reg.gauge("fimi/n_fis").set(float(result.n_fis))
     reg.gauge("fimi/n_classes").set(float(len(result.classes)))
     reg.gauge("fimi/replication").set(float(result.replication))
-    est_share = est / est.sum() if est.sum() > 0 else np.full(P, 1.0 / P)
-    obs_share = trips / trips.sum() if trips.sum() > 0 else np.full(P, 1.0 / P)
-    reg.gauge("fimi/load/estimation_error").set(
-        float(np.abs(est_share - obs_share).max())
-    )
-    occ = reg.histogram("fimi/frontier_occupancy")
-    K = max(1, int(params.eclat.frontier_size))
-    popped = (
-        result.nodes_popped.astype(np.float64).reshape(-1)
-        if result.nodes_popped is not None
-        else None
-    )
-    for p in range(P):
-        reg.gauge(f"fimi/shard{p}/est_load").set(float(est[p]))
-        reg.gauge(f"fimi/shard{p}/obs_trips").set(float(trips[p]))
-        if popped is not None and trips[p] > 0:
-            occ.record(float(popped[p]) / (trips[p] * K))
 
 
 def _coverage_sample_host(M: np.ndarray, n_fs: int, n_items: int, key) -> np.ndarray:

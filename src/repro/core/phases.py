@@ -86,6 +86,8 @@ class Phase1DeviceOut(NamedTuple):
     mfi_supports: jnp.ndarray
     mfi_count: jnp.ndarray       # int32
     overflow: jnp.ndarray        # int32 — any stack/output overflow
+    n_iters: jnp.ndarray         # int32 — sample-mine loop trips (0: seq)
+    n_popped: jnp.ndarray        # int32 — DFS nodes mined (0: par, seq)
 
 
 def _assigned_item_seeds(order: jnp.ndarray, n_items: int, p_idx, P: int):
@@ -161,6 +163,8 @@ def phase1_device(
             mfi_supports=jnp.zeros((mfi_cfg.max_out,), jnp.int32),
             mfi_count=jnp.zeros((), jnp.int32),
             overflow=jnp.zeros((), jnp.int32),
+            n_iters=jnp.zeros((), jnp.int32),
+            n_popped=jnp.zeros((), jnp.int32),
         )
 
     # vertical form of D̃ (identical on every processor)
@@ -199,6 +203,7 @@ def phase1_device(
             ),
             n_items=n_items,
             multi_support_fn=multi_support_fn,
+            scope="fimi/phase1/eclat_loop",
         )
         # The stream contains every FI of D̃ with |W| ≥ 2; singleton FIs are
         # exactly the class prefixes, which the partitioner handles through
@@ -214,6 +219,8 @@ def phase1_device(
             mfi_supports=jnp.zeros((mfi_cfg.max_out,), jnp.int32),
             mfi_count=jnp.zeros((), jnp.int32),
             overflow=res.stack_overflow,
+            n_iters=res.n_iters,
+            n_popped=res.n_popped,
         )
     elif variant == "par":
         res = mfi.mine_candidates_seeded(
@@ -237,6 +244,8 @@ def phase1_device(
             mfi_supports=res.supports,
             mfi_count=res.n_out,
             overflow=res.overflow,
+            n_iters=res.n_iters,
+            n_popped=jnp.zeros((), jnp.int32),
         )
     else:
         raise ValueError(f"unknown phase-1 variant {variant!r}")
@@ -255,6 +264,7 @@ class Phase3Out(NamedTuple):
     replication: jnp.ndarray   # float — Σ|D'_i| / |D| (thesis Ch. 10)
 
 
+@jax.named_scope("fimi/phase3/exchange")
 def phase3_exchange(
     local_tx: jnp.ndarray,       # uint32[T, IW] — D_i
     local_valid: jnp.ndarray,    # bool [T]
@@ -335,7 +345,7 @@ class Phase4Out(NamedTuple):
     overflow: jnp.ndarray
     work_iters: jnp.ndarray    # int32 — DFS trips (the load-balance metric)
     nodes_popped: jnp.ndarray  # int32 — DFS nodes mined; /(trips·K) is the
-    #                            frontier occupancy (obs histogram)
+    #                            lane fill (args of span fimi/phase4_mine)
 
 
 def phase4_mine(
@@ -386,6 +396,7 @@ def phase4_mine(
         n_items=n_items,
         support_fn=support_fn,
         multi_support_fn=multi_support_fn,
+        scope="fimi/phase4/eclat_loop",
     )
     return Phase4Out(
         fi_items=res.items,

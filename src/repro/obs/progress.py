@@ -1,8 +1,8 @@
 """Live mining progress/ETA from the paper's sample-based load estimates.
 
 Thm 6.1 of the source paper bounds how well a database sample predicts each
-processor's mining load; PR 4 used that only *post hoc* (the
-``fimi/load/estimation_error`` metric).  This module promotes it to a
+processor's mining load; the cluster executor uses that only *post hoc*
+(the ``cluster/load/estimation_error`` gauge).  This module promotes it to a
 runtime signal: a :class:`ProgressEstimator` is seeded with the planner's
 per-shard estimated loads (the same units ``schedule.loads_of`` /
 ``cluster.planner`` assign with) and fed observed completions as mining

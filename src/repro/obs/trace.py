@@ -26,6 +26,23 @@ disabled path must not change execution).  ``jax_profiler(log_dir)`` is the
 opt-in escape hatch to the real profiler (TensorBoard/XProf) when
 op-level device detail is needed.
 
+Profiler clock: while tracing, every ``span`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name (when the process has
+imported jax; the tracer never imports it), so a profiler session records
+the program's phases on the device trace's clock.  The tracer's own clock
+stays ``time.monotonic()``, ``ts`` in µs from the zero ``clear()`` sets.
+
+Counters on spans: ``with tr.span(name) as sp: ... sp.set(trips=n)`` adds
+numeric args before the span is recorded; the disabled span's ``set`` does
+nothing, so callers read device values for args only under
+``if tr.enabled:``.
+
+Compiles: while ``TRACER`` traces, each XLA backend compile (or load from
+the persistent compile cache) is recorded as a host span ``jax/compile``
+(arg ``fun``) on the compiling thread, from jax's
+``/jax/core/compile/backend_compile_duration`` event; the listener is
+registered by the first ``enable()`` after the process imported jax.
+
 The disabled fast path is a single attribute check returning a shared
 no-op context manager — no allocation, no clock read, no lock
 (benchmarked in ``benchmarks/io.py``: streamed-mine overhead with
@@ -34,6 +51,7 @@ everything enabled is gated < 5 %; disabled is in the noise).
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from collections import deque
@@ -47,6 +65,9 @@ from typing import Deque, Dict, Optional
 #: ``trace-truncated`` rule surfaces.
 DEFAULT_MAX_EVENTS = 500_000
 
+#: jax's monitoring event for one XLA backend compile.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
 
 class _NullSpan:
     """Shared do-nothing context manager — the disabled fast path."""
@@ -59,27 +80,45 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
+def _jax():
+    """jax if this process has imported it, else None (never imports it)."""
+    return sys.modules.get("jax")
+
+
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
         self._name = name
         self._args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        jax = _jax()
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(self._name)
+            self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
+    def set(self, **args) -> None:
+        """Add numeric args to the span before it is recorded."""
+        self._args = {**(self._args or {}), **args}
+
     def __exit__(self, *exc):
-        self._tracer._record(
-            self._name, self._t0, time.monotonic() - self._t0, self._args
-        )
+        t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._record(self._name, self._t0, t1 - self._t0, self._args)
         return False
 
 
@@ -104,6 +143,9 @@ class Tracer:
 
     def enable(self) -> None:
         self._enabled = True
+        jax = _jax()
+        if jax is not None:
+            _listen_for_compiles(jax)
 
     def disable(self) -> None:
         self._enabled = False
@@ -279,6 +321,26 @@ class Tracer:
 
 #: The process-global tracer every subsystem records into by default.
 TRACER = Tracer()
+
+_listening = False
+
+
+def _on_jax_event(event: str, start: float, end: float, **kw) -> None:
+    """jax monitoring listener: one ``jax/compile`` span per backend compile
+    while ``TRACER`` is on, ending now and lasting the compile's duration."""
+    if event != COMPILE_EVENT or not TRACER.enabled:
+        return
+    dur = end - start
+    TRACER._record("jax/compile", time.monotonic() - dur, dur,
+                   {"fun": str(kw.get("fun_name", ""))})
+
+
+def _listen_for_compiles(jax) -> None:
+    """Register :func:`_on_jax_event` with jax once per process."""
+    global _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_time_span_listener(_on_jax_event)
 
 
 def tracer() -> Tracer:

@@ -648,9 +648,17 @@ def test_mine_driver_trace_smoke(tmp_path):
     assert man["name"] == "mine" and "mine_wall_s" in man
     metrics = json.loads((run_dir / "metrics.json").read_text())
     assert metrics["counters"]["fimi/runs"] == 1
-    assert "fimi/load/estimation_error" in metrics["gauges"]
-    assert "fimi/frontier_occupancy" in metrics["histograms"]
-    assert any(k.startswith("fimi/shard") for k in metrics["gauges"])
+    assert metrics["counters"]["fimi/trips"] > 0
+    assert "fimi/n_fis" in metrics["gauges"]
+    # the loop counts ride on the phase spans, not on registry gauges
+    assert not any(k.startswith(("fimi/shard", "fimi/load"))
+                   for k in metrics["gauges"])
+    assert "fimi/frontier_occupancy" not in metrics["histograms"]
+    args = {e["name"]: e.get("args", {}) for e in trace["traceEvents"]
+            if e["ph"] == "X"}
+    assert args["fimi/phase4_mine"]["trips"] > 0
+    assert {"trips", "popped", "offers"} <= set(args["fimi/phase1_sample"])
+    assert "probes" in args["fimi/phase2_partition"]
     # the record is diffable against itself through the CLI
     assert obs_report.main(["diff", str(run_dir), str(run_dir)]) == 0
 
