@@ -17,7 +17,10 @@ Adaptation (see DESIGN.md):
     frequent extensions ascending by support before splitting into child
     PBECs (Prop. 2.23 keeps the classes disjoint for *any* per-node order);
   * the (optional) reservoir sampler runs *inside* the mining loop: the FI
-    stream never leaves the device (Alg. 9 / Vitter, §6.2.2).
+    stream never leaves the device (Alg. 9 / Vitter, §6.2.2).  Each trip
+    compacts its emitted itemsets into an index list and takes one
+    sequential Algorithm-R step per offered itemset, not one per slot of
+    the ``[K, I]`` frontier.
 
 All shapes are static; overflow of the stack or output buffer is counted and
 reported, never silently dropped.
@@ -96,29 +99,38 @@ def _lift_support_fn(support_fn: SupportFn) -> MultiSupportFn:
     return multi
 
 
-def _reservoir_update(state, itemsets_packed, supports, emit_mask, R):
-    """Algorithm R over the F·I itemset slots of this trip (sequential fori;
-    one step per slot, whether or not it emits)."""
+def _reservoir_update(state, node_items, e_packed, supports, emit, R):
+    """Algorithm R over the itemsets this trip offers: one sequential step per
+    offered itemset ``node_items[f] | {e}``, in ascending slot order f·I + e.
 
-    def body(i, carry):
-        res_items, res_supp, seen, key = carry
+    The emitted slots are compacted into an index list first, so the loop
+    runs ``n_emit`` steps (under ``vmap``, the most any miner offers) and
+    rebuilds each offered row from its index; nothing of size F·I·IW is
+    built.  One key split per offer, in slot order."""
+    F, I = emit.shape
+    with jax.named_scope("fimi/phase1/reservoir"):
+        flat = emit.reshape(F * I)
+        n_emit = flat.sum().astype(jnp.int32)
+        pos = jnp.where(flat, jnp.cumsum(flat) - 1, F * I)   # ≥F·I ⇒ dropped
+        slots = jnp.zeros((F * I,), jnp.int32).at[pos].set(
+            jnp.arange(F * I, dtype=jnp.int32), mode="drop"
+        )
 
-        def do(carry):
+        def body(k, carry):
             res_items, res_supp, seen, key = carry
+            f, e = jnp.divmod(slots[k], I)
             seen = seen + 1
             key, sub = jax.random.split(key)
             j = jax.random.randint(sub, (), 0, seen)
             slot = jnp.where(seen <= R, seen - 1, j)
             take = (seen <= R) | (j < R)
             slot = jnp.where(take, slot, R)  # R = out-of-bounds ⇒ drop
-            res_items = res_items.at[slot].set(itemsets_packed[i], mode="drop")
-            res_supp = res_supp.at[slot].set(supports[i], mode="drop")
+            res_items = res_items.at[slot].set(node_items[f] | e_packed[e],
+                                               mode="drop")
+            res_supp = res_supp.at[slot].set(supports[f, e], mode="drop")
             return res_items, res_supp, seen, key
 
-        return jax.lax.cond(emit_mask[i], do, lambda c: c, carry)
-
-    with jax.named_scope("fimi/phase1/reservoir"):
-        return jax.lax.fori_loop(0, emit_mask.shape[0], body, state)
+        return jax.lax.fori_loop(0, n_emit, body, state)
 
 
 @partial(
@@ -247,9 +259,10 @@ def mine_seeded(
         if config.reservoir_size > 0:
             res_items, res_supp, res_seen, key = _reservoir_update(
                 (s.res_items, s.res_supp, s.res_seen, s.key),
-                flat_items,
-                flat_supp,
-                freq.reshape(F * I),
+                node_items,
+                e_packed,
+                supports,
+                freq,
                 config.reservoir_size,
             )
         else:

@@ -63,7 +63,7 @@ def test_phase1_and_phase4_record_loop_counts(runs):
     a = p1["args"]
     assert a["P"] == P and a["K"] == K and a["I"] == 24
     assert 0 < a["popped"] <= P * a["trips"] * K
-    # every itemset the reservoir saw took one of its F·I steps
+    # the reservoir took one step per offer, at most one per F·I slot
     assert 0 < a["offers"] <= P * a["trips"] * K * 24
     (p4,) = _spans(events, "fimi/phase4_mine")
     b = p4["args"]
