@@ -5,7 +5,9 @@
               by replicated-transaction volume.
 ``executor``  Phase-3/4 data plane: all_to_all transaction exchange +
               frontier-batched Eclat per shard under ``jax.shard_map`` (or
-              vmap simulation), merged into one global :class:`FITable`.
+              vmap simulation), merged into one global :class:`FITable`;
+              ``mine_store`` runs plan → assemble → rounds → merge on an
+              on-disk store (the launcher's and the benchmark's path).
 ``rebalance`` Dynamic correction: per-round load telemetry, bounded donation
               of unexplored PBEC subtrees from overloaded to idle shards.
 ``checkpoint`` Fault tolerance: atomic round-granular checkpoints (CRC32C-
@@ -25,6 +27,7 @@ from repro.cluster.executor import (  # noqa: F401
     RoundStats,
     cluster_mine_fn,
     execute,
+    mine_store,
 )
 from repro.cluster.planner import (  # noqa: F401
     MiningPlan,

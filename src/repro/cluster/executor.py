@@ -29,6 +29,7 @@ enough devices exist (``launch/cluster_mine.py`` forks host devices).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from functools import partial
 from typing import Callable, Dict, List, Optional
@@ -238,6 +239,10 @@ class ClusterResult:
     report: ClusterReport
 
 
+#: process-wide count of cluster mines: the ``mine`` arg of their spans
+_MINES = itertools.count(1)
+
+
 def _auto_spmd(P: int, spmd, mesh):
     """Resolve the SPMD combinator: real devices when available, else vmap."""
     if spmd is not None:
@@ -264,6 +269,7 @@ def execute(
     progress_cb: Optional[
         Callable[[obs_progress.ProgressSnapshot], None]
     ] = None,
+    mine: Optional[int] = None,
 ) -> ClusterResult:
     """Run the full distributed pipeline; returns table + plan + telemetry.
 
@@ -280,6 +286,14 @@ def execute(
     round — the drivers print its ``line()`` — fed from the planner's
     estimated loads and the observed per-round completions (ETA math in
     :mod:`repro.obs.progress`).
+
+    While tracing, every ``cluster/*`` span carries ``mine``, this mine's
+    number in the process (``mine_store`` passes its own).  ``cluster/plan``
+    wraps the plan only when the executor makes it; ``cluster/exchange``
+    carries ``rows_moved`` (rows that left their miner), ``replication`` and
+    ``overflow``; ``cluster/mine`` covers the round's mine and the
+    rebalancer's decision after it, with ``trips`` (per miner) and
+    ``donations``.  Those args are read off the device only while tracing.
     """
     P, T, IW = tx_shards.shape
     spmd, mesh, backend = _auto_spmd(P, spmd, mesh)
@@ -290,9 +304,10 @@ def execute(
     phase_ms = {"plan": 0.0, "exchange": 0.0, "mine": 0.0, "merge": 0.0}
 
     tr = obs_trace.TRACER
+    mine = next(_MINES) if mine is None else mine
     t0 = time.perf_counter()
-    with tr.span("cluster/plan", P=P, backend=backend):
-        if plan is None:
+    if plan is None:
+        with tr.span("cluster/plan", P=P, backend=backend, mine=mine):
             plan = planner_mod.plan(
                 tx_shards,
                 n_items,
@@ -398,7 +413,8 @@ def execute(
         prefix_packed = np.asarray(bm.pack_bool(jnp.asarray(prefix_rows)))
 
         t0 = time.perf_counter()
-        with tr.span("cluster/exchange", round=r, classes=len(round_ids)):
+        with tr.span("cluster/exchange", round=r, classes=len(round_ids),
+                     mine=mine) as sp:
             out3 = p3(
                 tx_shards,
                 local_valid,
@@ -410,6 +426,15 @@ def execute(
                 jnp.broadcast_to(jnp.asarray(class_assign), (P, C_round)),
             )
             out3 = jax.block_until_ready(out3)
+            if tr.enabled:
+                # recv_counts[dst, src]: rows dst took from src; the
+                # diagonal never left its chip
+                recv, repl, over = jax.device_get(
+                    (out3.recv_counts, out3.replication, out3.overflow))
+                recv = np.asarray(recv).reshape(P, P)
+                sp.set(rows_moved=int(recv.sum() - np.trace(recv)),
+                       replication=float(np.reshape(repl, -1)[0]),
+                       overflow=int(np.reshape(over, -1)[0]))
         phase_ms["exchange"] += (time.perf_counter() - t0) * 1e3
 
         # ---- Phase 4: mine this round's classes on the received slabs -----
@@ -419,8 +444,8 @@ def execute(
         keys4 = jax.vmap(lambda i: jax.random.fold_in(key, i))(
             r * P + jnp.arange(P)
         )
-        mine_t0 = time.perf_counter()
-        with tr.span("cluster/mine", round=r, chunk=chunk):
+        with tr.span("cluster/mine", round=r, chunk=chunk, mine=mine) as sp:
+            mine_t0 = time.perf_counter()
             out4 = p4(
                 out3.slab.reshape(P, -1, IW),
                 out3.slab_valid.reshape(P, -1),
@@ -434,7 +459,24 @@ def execute(
                 keys4,
             )
             out4 = jax.device_get(out4)
-        mine_s = time.perf_counter() - mine_t0
+            mine_s = time.perf_counter() - mine_t0
+            trips = np.asarray(out4.work_iters).reshape(P).astype(np.float64)
+            est_mined = np.array(
+                [sum(max(float(est_sizes[c]), 1.0) for c in ids)
+                 for ids in take]
+            )
+            ledger.record_round(trips, est_mined)
+            moved: List[rebalance_mod.Donation] = []
+            if params.rebalance and any(queues):
+                moved = rebalance_mod.rebalance(
+                    queues,
+                    est_sizes,
+                    ledger,
+                    round_index=r,
+                    skew_threshold=params.skew_threshold,
+                    max_donations=params.max_donations,
+                )
+            sp.set(trips=trips.astype(int).tolist(), donations=len(moved))
         phase_ms["mine"] += mine_s * 1e3
 
         exchange_overflow += int(np.asarray(out3.overflow).reshape(-1)[0])
@@ -452,11 +494,6 @@ def execute(
                 fi_supports.append(supps[p, :n])
         anc_supports = np.asarray(out4.prefix_supports).reshape(P, -1)[0]
 
-        trips = np.asarray(out4.work_iters).reshape(P).astype(np.float64)
-        est_mined = np.array(
-            [sum(max(float(est_sizes[c]), 1.0) for c in ids) for ids in take]
-        )
-        ledger.record_round(trips, est_mined)
         snap = progress.update(est_mined, trips)
         if progress_cb is not None:
             progress_cb(snap)
@@ -495,23 +532,13 @@ def execute(
                     },
                 )
 
-        moved: List[rebalance_mod.Donation] = []
-        if params.rebalance and any(queues):
-            moved = rebalance_mod.rebalance(
-                queues,
-                est_sizes,
-                ledger,
-                round_index=r,
-                skew_threshold=params.skew_threshold,
-                max_donations=params.max_donations,
+        donations.extend(moved)
+        for d in moved:
+            tr.instant(
+                "cluster/donate",
+                round=d.round_index, class_id=d.class_id,
+                src=d.src, dst=d.dst,
             )
-            donations.extend(moved)
-            for d in moved:
-                tr.instant(
-                    "cluster/donate",
-                    round=d.round_index, class_id=d.class_id,
-                    src=d.src, dst=d.dst,
-                )
         rounds.append(
             RoundStats(
                 round_index=r,
@@ -562,25 +589,25 @@ def execute(
 
     # ---- merge: one global table = all shards' FIs + frequent ancestors ---
     t0 = time.perf_counter()
-    if anc_supports is None:  # no classes at all ⇒ still need prefix supports
-        anc_supports = np.zeros((A,), np.int64)
-    n_anc = plan.n_ancestors
-    anc_keep = np.zeros((A,), bool)
-    anc_keep[:n_anc] = anc_supports[:n_anc] >= plan.abs_minsup
-    if anc_keep.any():
-        fi_masks.append(
-            np.asarray(bm.pack_bool(jnp.asarray(plan.ancestor_masks[anc_keep])))
+    with tr.span("cluster/merge", mine=mine):
+        if anc_supports is None:  # no classes ⇒ still need prefix supports
+            anc_supports = np.zeros((A,), np.int64)
+        n_anc = plan.n_ancestors
+        anc_keep = np.zeros((A,), bool)
+        anc_keep[:n_anc] = anc_supports[:n_anc] >= plan.abs_minsup
+        if anc_keep.any():
+            fi_masks.append(np.asarray(
+                bm.pack_bool(jnp.asarray(plan.ancestor_masks[anc_keep]))))
+            fi_supports.append(anc_supports[anc_keep])
+        if fi_masks:
+            masks = np.concatenate(fi_masks, axis=0).astype(np.uint32)
+            supports = np.concatenate(fi_supports, axis=0).astype(np.int64)
+        else:
+            masks = np.zeros((0, bm.n_words(n_items)), np.uint32)
+            supports = np.zeros((0,), np.int64)
+        table = FITable(
+            masks=masks, supports=supports, n_items=n_items, n_tx=plan.n_tx
         )
-        fi_supports.append(anc_supports[anc_keep])
-    if fi_masks:
-        masks = np.concatenate(fi_masks, axis=0).astype(np.uint32)
-        supports = np.concatenate(fi_supports, axis=0).astype(np.int64)
-    else:
-        masks = np.zeros((0, bm.n_words(n_items)), np.uint32)
-        supports = np.zeros((0,), np.int64)
-    table = FITable(
-        masks=masks, supports=supports, n_items=n_items, n_tx=plan.n_tx
-    )
     phase_ms["merge"] = (time.perf_counter() - t0) * 1e3
 
     report = ClusterReport(
@@ -596,6 +623,59 @@ def execute(
     )
     report.emit()
     return ClusterResult(table=table, plan=plan, report=report)
+
+
+def mine_store(
+    store,
+    params: ClusterParams,
+    key: jax.Array,
+    P: int,
+    *,
+    adjust_plan: Optional[
+        Callable[[planner_mod.MiningPlan], planner_mod.MiningPlan]
+    ] = None,
+    **execute_kw,
+) -> ClusterResult:
+    """Mine an on-disk :class:`repro.store.TxStore` on P miners, end to end.
+
+    Plans off disk (the Thm 6.1 sample gathered block by block, bit-exact
+    with the in-RAM sample), assembles the ``[P, T, IW]`` row shards through
+    the double-buffered reader (two blocks on the host), places them one
+    per device of the miner mesh (``shard_map`` when at least P devices
+    exist, else ``vmap`` on one), runs the rounds and merges into one
+    :class:`FITable`.  ``adjust_plan``
+    rewrites the plan before any round (fault injection); ``execute_kw``
+    goes to :func:`execute` (checkpointing, hooks, progress).
+
+    ``report.phase_ms`` charges ``plan`` to the off-disk planning and adds
+    ``assemble``.  While tracing the mine is span ``cluster/run``, with
+    ``cluster/plan`` and ``cluster/assemble`` inside it beside the
+    executor's spans, all carrying ``mine``.
+    """
+    from repro.store import reader as store_reader
+
+    mine = next(_MINES)
+    tr = obs_trace.TRACER
+    with tr.span("cluster/run", P=P, mine=mine):
+        t0 = time.perf_counter()
+        with tr.span("cluster/plan", P=P, mine=mine):
+            plan = planner_mod.plan(store, None, params.planner, key, P=P)
+            if adjust_plan is not None:
+                plan = adjust_plan(plan)
+        t1 = time.perf_counter()
+        spmd, mesh, _ = _auto_spmd(P, None, None)
+        with tr.span("cluster/assemble", P=P, mine=mine):
+            shards = store_reader.to_device_shards(store, P)
+            if mesh is not None:
+                shards = store_reader.place_on_mesh(shards, mesh)
+            shards = jax.block_until_ready(shards)
+        t2 = time.perf_counter()
+        res = execute(shards, store.n_items, params, key, spmd=spmd,
+                      mesh=mesh, plan=plan, mine=mine, **execute_kw)
+    res.report.phase_ms["plan"] = (t1 - t0) * 1e3
+    res.report.phase_ms["assemble"] = (t2 - t1) * 1e3
+    res.report.republish_gauges()
+    return res
 
 
 # ---------------------------------------------------------------------------

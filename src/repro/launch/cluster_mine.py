@@ -58,10 +58,11 @@ def run_once(dense, n_items, P, args, eclat_mod, fimi_mod, cluster,
              store=None):
     """One executor run at P miners; returns (result, wall seconds).
 
-    With ``store`` set, the plan is computed **off disk** (Thm 6.1 sample
-    via ``store.reader.sample_rows`` — bit-exact vs the in-RAM sample) and
-    the data-plane shards are assembled block-by-block through the
-    double-buffered reader; ``dense`` is only used otherwise.
+    With ``store`` set, the mine is ``cluster.mine_store``: the plan is
+    computed **off disk** (Thm 6.1 sample via ``store.reader.sample_rows``
+    — bit-exact vs the in-RAM sample) and the data-plane shards are
+    assembled block-by-block through the double-buffered reader;
+    ``dense`` is only used otherwise.
     """
     import jax
 
@@ -95,20 +96,11 @@ def run_once(dense, n_items, P, args, eclat_mod, fimi_mod, cluster,
     )
     t0 = time.perf_counter()
     if store is not None:
-        from repro.store.reader import to_device_shards
-
-        plan = cluster.plan(store, None, params.planner, key, P=P)
-        if force_skew:
-            plan = _skew_plan(plan)
-        t1 = time.perf_counter()
-        shards = jax.block_until_ready(to_device_shards(store, P))
-        t2 = time.perf_counter()
-        res = cluster.execute(shards, n_items, params, key, plan=plan, **ck)
-        # execute() saw a precomputed plan (plan≈0): charge the off-disk
-        # planning + block-streamed assembly where they actually happened
-        res.report.phase_ms["plan"] = (t1 - t0) * 1e3
-        res.report.phase_ms["assemble"] = (t2 - t1) * 1e3
-        res.report.republish_gauges()
+        # plan off disk, assemble block by block, mine one miner per device
+        # (phase_ms: plan, exchange, mine, merge, assemble)
+        res = cluster.mine_store(
+            store, params, key, P,
+            adjust_plan=_skew_plan if force_skew else None, **ck)
     else:
         shards = fimi_mod.shard_db(dense, P)
         if force_skew:

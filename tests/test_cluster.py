@@ -283,6 +283,199 @@ def test_cluster_shard_map_parity_subprocess():
 
 
 # ---------------------------------------------------------------------------
+# The store path: cluster.mine_store, its spans and their counts
+# ---------------------------------------------------------------------------
+
+
+def _store_of(dense, path, block_tx=64):
+    from repro.store import StoreWriter
+
+    w = StoreWriter(str(path), n_items=dense.shape[1], block_tx=block_tx)
+    w.append_dense(dense)
+    return w.close()
+
+
+def _exchange_counts(dense, P, prefixes_per_miner):
+    """Host count of one round's exchange: ``(rows that leave their miner,
+    rows held after it over |D|)``.  Miner p needs every row that holds the
+    prefix of one of its classes; miner q holds rows ``[q·T, (q+1)·T)``."""
+    T = dense.shape[0] // P
+    rows = np.asarray(dense[: P * T], bool)
+    need = np.zeros((P * T, P), bool)
+    for p, prefixes in enumerate(prefixes_per_miner):
+        for prefix in prefixes:
+            need[:, p] |= rows[:, np.asarray(prefix, bool)].all(axis=1)
+    owner = np.repeat(np.arange(P), T)
+    leaving = int(need.sum() - need[np.arange(P * T), owner].sum())
+    return leaving, float(need.sum()) / (P * T)
+
+
+def _host_spans(events, name):
+    return [e for e in events if e.get("ph") == "X" and e["name"] == name
+            and e.get("cat", "host") == "host"]
+
+
+@pytest.fixture(scope="module")
+def traced_store_mine(small_db, tmp_path_factory):
+    """One traced ``mine_store`` of a store of the small DB (P=4 vmapped,
+    chunk 2: several rounds), the classes each round handed each miner,
+    and an untraced mine of the same store."""
+    from repro.cluster import planner as planner_mod
+    from repro.obs import trace as obs_trace
+
+    dense, _, _, oracle = small_db
+    store = _store_of(dense, tmp_path_factory.mktemp("st") / "st")
+    params = cluster.ClusterParams(planner=_planner_params(n_fi_sample=32),
+                                   chunk=2, skew_threshold=1.05)
+    taken = []
+    real_pack = planner_mod.pack_seeds
+
+    def pack_seeds(classes, ids_per_shard, *a, **k):
+        taken.append([[classes[c].prefix for c in ids] for ids in ids_per_shard])
+        return real_pack(classes, ids_per_shard, *a, **k)
+
+    tr = obs_trace.TRACER
+    tr.clear()
+    tr.enable()
+    planner_mod.pack_seeds = pack_seeds
+    try:
+        res = cluster.mine_store(store, params, jax.random.PRNGKey(1), 4)
+    finally:
+        planner_mod.pack_seeds = real_pack
+        tr.disable()
+    events = tr.export()["traceEvents"]
+    tr.clear()
+    off = cluster.mine_store(store, params, jax.random.PRNGKey(1), 4)
+    n_off = tr.n_events
+    return dense, oracle, res, events, taken, off, n_off
+
+
+def test_mine_store_is_exact_and_reports_its_phases(traced_store_mine):
+    dense, oracle, res, _, _, off, _ = traced_store_mine
+    assert res.report.backend == "vmap"
+    assert res.table.to_dict() == oracle == off.table.to_dict()
+    assert set(res.report.phase_ms) == {"plan", "exchange", "mine", "merge",
+                                        "assemble"}
+    assert res.report.phase_ms["plan"] > 0
+    assert res.report.phase_ms["assemble"] > 0
+
+
+def test_mine_store_spans_one_mine(traced_store_mine):
+    _, _, res, events, _, _, n_off = traced_store_mine
+    run = _host_spans(events, "cluster/run")
+    assert len(run) == 1
+    mine = run[0]["args"]["mine"]
+    for name in ("cluster/plan", "cluster/assemble", "cluster/merge"):
+        got = _host_spans(events, name)
+        assert len(got) == 1, name
+        assert got[0]["args"]["mine"] == mine
+        assert run[0]["ts"] <= got[0]["ts"]
+        assert got[0]["ts"] + got[0]["dur"] <= run[0]["ts"] + run[0]["dur"]
+    for name in ("cluster/exchange", "cluster/mine"):
+        got = _host_spans(events, name)
+        assert len(got) == res.report.n_rounds, name
+        assert {e["args"]["mine"] for e in got} == {mine}
+    assert n_off == 0          # tracing off records nothing
+
+
+def test_exchange_and_mine_spans_count_what_the_rounds_did(traced_store_mine):
+    """``rows_moved`` and ``replication`` on ``cluster/exchange`` equal a
+    host count over each round's class table; ``trips`` and ``donations``
+    on ``cluster/mine`` equal the round's RoundStats."""
+    dense, _, res, events, taken, _, _ = traced_store_mine
+    rounds = res.report.rounds
+    assert len(rounds) > 1 and len(taken) == len(rounds)
+    assert sum(len(r.donations) for r in rounds) > 0
+    exchange = _host_spans(events, "cluster/exchange")
+    mined = _host_spans(events, "cluster/mine")
+    for r, ex, mi, prefixes in zip(rounds, exchange, mined, taken):
+        leaving, replication = _exchange_counts(dense, 4, prefixes)
+        assert ex["args"]["rows_moved"] == leaving > 0
+        assert ex["args"]["replication"] == pytest.approx(r.replication)
+        assert r.replication == pytest.approx(replication, rel=1e-6)
+        assert ex["args"]["overflow"] == 0
+        assert mi["args"]["trips"] == r.work_iters.tolist()
+        assert mi["args"]["donations"] == len(r.donations)
+
+
+_STORE_SCRIPT = """
+import json, os, sys, tempfile
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax
+from repro import cluster
+from repro.cluster import planner as planner_mod
+from repro.core import eclat, fimi
+from repro.data.ibm_gen import IBMParams, generate_dense
+from repro.obs import trace as obs_trace
+from repro.store import StoreWriter
+
+dense = generate_dense(IBMParams(n_tx=256, n_items=16, n_patterns=6,
+                                 avg_pattern_len=4, avg_tx_len=6, seed=11))
+w = StoreWriter(os.path.join(tempfile.mkdtemp(), "st"), n_items=16,
+                block_tx=48)
+w.append_dense(dense)
+store = w.close()
+params = cluster.ClusterParams(
+    planner=cluster.PlannerParams(min_support_rel=0.1, n_db_sample=128,
+                                  n_fi_sample=64, alpha=0.7), chunk=2)
+taken = []
+real_pack = planner_mod.pack_seeds
+def pack_seeds(classes, ids, *a, **k):
+    taken.append([[np.nonzero(classes[c].prefix)[0].tolist() for c in i]
+                  for i in ids])
+    return real_pack(classes, ids, *a, **k)
+planner_mod.pack_seeds = pack_seeds
+obs_trace.TRACER.enable()
+res = cluster.mine_store(store, params, jax.random.PRNGKey(2), 4)
+obs_trace.TRACER.disable()
+oracle = eclat.brute_force_fis(dense, int(np.ceil(0.1 * 256)))
+fp = fimi.FimiParams(min_support_rel=0.1, n_db_sample=128, n_fi_sample=64,
+                     alpha=0.7)
+ref = fimi.run(fimi.shard_db(dense, 1), 16, fp, jax.random.PRNGKey(2),
+               materialize=True)
+got = res.table.to_dict()
+ex = [e["args"] for e in obs_trace.TRACER.export()["traceEvents"]
+      if e.get("name") == "cluster/exchange"]
+print("MINE_STORE " + json.dumps({
+    "backend": res.report.backend, "oracle": got == oracle,
+    "fimi": got == ref.fi_dict, "n": len(got), "taken": taken,
+    "rows_moved": [a["rows_moved"] for a in ex]}))
+"""
+
+
+def test_mine_store_shard_map_subprocess():
+    """4 host devices: ``mine_store`` places one miner per device, equals
+    the brute-force oracle and one-device ``fimi.run``, and its
+    ``rows_moved`` under ``shard_map`` equals the host count."""
+    import json
+    import os
+    from pathlib import Path
+
+    from repro.data.ibm_gen import IBMParams, generate_dense
+
+    r = subprocess.run(
+        [sys.executable, "-c", _STORE_SCRIPT],
+        capture_output=True, text=True, timeout=900,
+        env={**os.environ, "PYTHONPATH": "src"},
+        cwd=str(Path(__file__).resolve().parents[1]),
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    line = next(x for x in r.stdout.splitlines()
+                if x.startswith("MINE_STORE "))
+    got = json.loads(line[len("MINE_STORE "):])
+    assert got["backend"] == "shard_map"
+    assert got["oracle"] and got["fimi"] and got["n"] > 0
+    dense = generate_dense(IBMParams(n_tx=256, n_items=16, n_patterns=6,
+                                     avg_pattern_len=4, avg_tx_len=6,
+                                     seed=11))
+    assert len(got["taken"]) == len(got["rows_moved"]) > 1
+    for items_per_miner, moved in zip(got["taken"], got["rows_moved"]):
+        prefixes = [[np.isin(np.arange(16), items) for items in miner]
+                    for miner in items_per_miner]
+        assert moved == _exchange_counts(dense, 4, prefixes)[0]
+
+
+# ---------------------------------------------------------------------------
 # StreamingMiner integration — distributed re-mines
 # ---------------------------------------------------------------------------
 

@@ -4,9 +4,12 @@ Each test lowers one dispatch with ``force="pallas"`` and compiles it for a
 described ``v5e:2x2`` topology at the widths ``chip_smoke.py`` runs: 1,000
 items, the 100,000-transaction Phase-4 slab (3,125 tid words) and K=16
 frontier nodes; serving at Q=256 queries over 32-word masks; streaming at
-S=2 blocks of 4,096 rows.  The compiler refuses unaligned tiles and VMEM
-overuse here exactly as on the chip.  The topology is described inside a
-fixture, never at import: only one process may load the TPU library.
+S=2 blocks of 4,096 rows.  The four-chip cluster mine's Phase-3 exchange
+and Phase-4 mine compile under ``shard_map`` over the 2x2's four chips at
+the widths of a 1,000,000-row store (one 250,000-row shard per chip).  The
+compiler refuses unaligned tiles and VMEM overuse here exactly as on the
+chip.  The topology is described inside a fixture, never at import: only
+one process may load the TPU library.
 """
 from functools import partial
 
@@ -25,7 +28,7 @@ T = 4096                                # rows per arrive/expire block
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.experimental import topologies
 
@@ -39,8 +42,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -88,3 +96,47 @@ def test_compile_cache_dir_env_wins(monkeypatch, tmp_path):
     assert fixed.endswith("/.jax_cache")
     assert (compile_cache.CHECKOUT_CACHE.parent / "chip_smoke.py").exists()
     assert fixed == compile_cache.cache_dir()   # fixed: no pid, temp or time
+
+
+T4, CHUNK4, A4 = 250_000, 8, 64        # rows per chip, classes per round
+
+
+def _cluster_phase(phase: str):
+    """``(fn, [(shape, dtype)])`` of one executor phase over P miners."""
+    from repro.core import eclat, fimi, phases
+
+    if phase == "exchange":
+        C = P * CHUNK4
+        fn = partial(phases.phase3_exchange, axis_name=fimi.AXIS,
+                     capacity=T4)
+        return fn, [((P, T4, IW), jnp.uint32), ((P, T4), jnp.bool_),
+                    ((P, C, IW), jnp.uint32), ((P, C), jnp.bool_),
+                    ((P, C), jnp.int32)]
+    cfg = eclat.EclatConfig(max_out=1 << 15, max_stack=8192, frontier_size=K)
+    fn = partial(phases.phase4_mine, axis_name=fimi.AXIS, n_items=I,
+                 eclat_cfg=cfg,
+                 multi_support_fn=ops.support_fns("pallas", False)[1])
+    return fn, [((P, P * T4, IW), jnp.uint32), ((P, P * T4), jnp.bool_),
+                ((P, T4, IW), jnp.uint32), ((P, T4), jnp.bool_),
+                ((P, CHUNK4, I), jnp.bool_), ((P, CHUNK4, I), jnp.bool_),
+                ((P, CHUNK4), jnp.bool_), ((P, A4, I), jnp.bool_),
+                ((P,), jnp.int32), ((P, 2), jnp.uint32)]
+
+
+@pytest.mark.parametrize("phase", ["exchange", "mine"])
+def test_cluster_phase_compiles_for_four_v5e_chips(topo, phase):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core import fimi
+
+    mesh = Mesh(topo.devices[:P], (fimi.AXIS,))
+    miners = NamedSharding(mesh, PartitionSpec(fimi.AXIS))
+    fn, shapes = _cluster_phase(phase)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=miners) for s, t in shapes]
+    compiled = fimi.shard_map_spmd(fn, P, mesh).lower(*args).compile()
+    text = compiled.as_text()
+    assert ("all-to-all" if phase == "exchange" else "tpu_custom_call") in text
+    mem = compiled.memory_analysis()      # bytes on each chip
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held < 16e9
