@@ -476,7 +476,10 @@ def execute(
                     skew_threshold=params.skew_threshold,
                     max_donations=params.max_donations,
                 )
-            sp.set(trips=trips.astype(int).tolist(), donations=len(moved))
+            sp.set(trips=trips.astype(int).tolist(),
+                   pushed=np.asarray(out4.nodes_pushed).reshape(P)
+                   .astype(int).tolist(),
+                   donations=len(moved))
         phase_ms["mine"] += mine_s * 1e3
 
         exchange_overflow += int(np.asarray(out3.overflow).reshape(-1)[0])

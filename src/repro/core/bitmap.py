@@ -75,6 +75,37 @@ def unpack_bool(packed: jnp.ndarray, n: int) -> jnp.ndarray:
     return flat[..., :n].astype(jnp.bool_)
 
 
+#: Hacker's Delight §7-3: the shift and mask of each swap of a 32×32 bit
+#: transpose, widest blocks first.
+_TRANSPOSE_STEPS = ((16, 0x0000FFFF), (8, 0x00FF00FF), (4, 0x0F0F0F0F),
+                    (2, 0x33333333), (1, 0x55555555))
+
+
+def transpose_bits(packed: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Bit-matrix transpose of packed rows ``uint32[..., R, C]`` into the
+    first ``n`` columns as packed rows ``uint32[..., n, n_words(R)]``; equal
+    to ``pack_bool(swapaxes(unpack_bool(packed, n), -1, -2))``.
+
+    Each 32×32 bit block is transposed in its own words by five masked
+    swaps, so nothing wider than the input is built (unpacking first holds
+    32× the input as ``uint32``)."""
+    *lead, R, C = packed.shape
+    nb = n_words(R)
+    x = jnp.pad(packed.astype(_U32),
+                [(0, 0)] * len(lead) + [(0, nb * WORD_BITS - R), (0, 0)])
+    # [..., 32 (row within block), C, nb]: the long block axis stays minor
+    x = jnp.moveaxis(x.reshape(*lead, nb, WORD_BITS, C), -3, -1)
+    for s, m in _TRANSPOSE_STEPS:
+        x = x.reshape(*lead, WORD_BITS // (2 * s), 2, s, C, nb)
+        lo, hi = x[..., 0, :, :, :], x[..., 1, :, :, :]
+        t = ((lo >> s) ^ hi) & _U32(m)
+        x = jnp.stack([lo ^ (t << s), hi ^ t], axis=-4)
+        x = x.reshape(*lead, WORD_BITS, C, nb)
+    # x[..., j, c, b]: column 32c + j over rows 32b .. 32b + 31
+    x = jnp.swapaxes(x, -3, -2).reshape(*lead, C * WORD_BITS, nb)
+    return x[..., :n, :]
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class BitmapDB:

@@ -13,6 +13,10 @@ Adaptation (see DESIGN.md):
     surviving children of the whole frontier are pushed back with a single
     vectorized scatter.  K=1 reproduces the classic one-node-per-trip DFS
     exactly and serves as the parity oracle;
+  * the stack holds no tidlist per node: a node is (its parent's row in a
+    tidlist arena, its last item), and ``T(node) = arena[row] & T(item)``
+    is rebuilt at pop.  A trip writes one ``[K, W]`` block of the pushing
+    nodes' tidlists, never a ``[K, I, W]`` block of children;
   * dynamic item re-ordering by support (§B.4.2) is kept: each node sorts its
     frequent extensions ascending by support before splitting into child
     PBECs (Prop. 2.23 keeps the classes disjoint for *any* per-node order);
@@ -64,13 +68,17 @@ class EclatResult(NamedTuple):
     n_iters: jnp.ndarray     # int32 — loop trips executed
     n_popped: jnp.ndarray    # int32 — DFS nodes mined; /(n_iters·K) =
     #                          frontier occupancy (the batching efficiency)
+    n_pushed: jnp.ndarray    # int32 — children pushed onto the stack (kept
+    #                          pushes; dropped ones are ``stack_overflow``)
 
 
 class _State(NamedTuple):
     sp: jnp.ndarray
     stk_items: jnp.ndarray   # uint32[S, IW]
     stk_ext: jnp.ndarray     # uint32[S, IW]
-    stk_tid: jnp.ndarray     # uint32[S, W]
+    stk_ref: jnp.ndarray     # int32[S] — arena row of the node's parent
+    stk_item: jnp.ndarray    # int32[S] — node's last item (I: none, a seed)
+    arena: jnp.ndarray       # uint32[S + F, W] — parent tidlists (seeds: own)
     out_items: jnp.ndarray
     out_supp: jnp.ndarray
     n_out: jnp.ndarray
@@ -82,6 +90,7 @@ class _State(NamedTuple):
     key: jax.Array
     it: jnp.ndarray
     popped: jnp.ndarray      # DFS nodes popped over all trips
+    pushed: jnp.ndarray      # children pushed (kept) over all trips
 
 
 #: single-prefix support plug-in: (item_bits[I, W], tid[W]) -> int32[I]
@@ -181,21 +190,24 @@ def mine_seeded(
     assert K <= S, "seed count exceeds stack capacity"
     F = max(1, min(config.frontier_size, S))   # frontier width per trip
 
-    # Compact valid seeds to the bottom of the stack.
+    # Compact valid seeds to the bottom of the stack.  The seed at stack
+    # position k keeps its own tidlist in arena row k, under item I (none).
     seed_valid = seed_valid.astype(jnp.bool_)
-    rank = jnp.cumsum(seed_valid) - 1
-    pos = jnp.where(seed_valid, rank, S)
+    seed_order = jnp.argsort(~seed_valid, stable=True)
     n_seeds = seed_valid.sum().astype(jnp.int32)
+
+    def bottom(rows, seeds):
+        return rows.at[:K].set(seeds[seed_order])
 
     init = _State(
         sp=n_seeds,
-        stk_items=jnp.zeros((S, IW), _U32)
-        .at[pos]
-        .set(bm.pack_bool(seed_prefix.astype(jnp.bool_)), mode="drop"),
-        stk_ext=jnp.zeros((S, IW), _U32)
-        .at[pos]
-        .set(bm.pack_bool(seed_ext.astype(jnp.bool_)), mode="drop"),
-        stk_tid=jnp.zeros((S, W), _U32).at[pos].set(seed_tid, mode="drop"),
+        stk_items=bottom(jnp.zeros((S, IW), _U32),
+                         bm.pack_bool(seed_prefix.astype(jnp.bool_))),
+        stk_ext=bottom(jnp.zeros((S, IW), _U32),
+                       bm.pack_bool(seed_ext.astype(jnp.bool_))),
+        stk_ref=jnp.arange(S, dtype=jnp.int32),
+        stk_item=jnp.full((S,), I, jnp.int32),
+        arena=bottom(jnp.zeros((S + F, W), _U32), seed_tid),
         out_items=jnp.zeros((O, IW), _U32),
         out_supp=jnp.zeros((O,), jnp.int32),
         n_out=jnp.asarray(0, jnp.int32),
@@ -207,6 +219,7 @@ def mine_seeded(
         key=key,
         it=jnp.asarray(0, jnp.int32),
         popped=jnp.asarray(0, jnp.int32),
+        pushed=jnp.asarray(0, jnp.int32),
     )
 
     # Constant across iterations: packed one-hot masks of every item
@@ -223,7 +236,11 @@ def mine_seeded(
         idx_c = jnp.maximum(idx, 0)
         node_items = s.stk_items[idx_c]        # uint32[F, IW]
         node_ext = s.stk_ext[idx_c]            # uint32[F, IW]
-        node_tid = s.stk_tid[idx_c]            # uint32[F, W]
+        # T(node) = T(parent) ∩ T(last item); item I (a seed) ANDs all ones.
+        item = s.stk_item[idx_c]               # [F]
+        item_row = jnp.where((item < I)[:, None],
+                             item_bits[jnp.minimum(item, I - 1)], ~_U32(0))
+        node_tid = s.arena[s.stk_ref[idx_c]] & item_row   # uint32[F, W]
         # Inactive lanes alias stack slot 0; masking their extension sets to ∅
         # makes them emit and push nothing.
         ext_bool = bm.unpack_bool(node_ext, I) & active[:, None]   # [F, I]
@@ -278,7 +295,6 @@ def mine_seeded(
         later = rank[:, None, :] > rank[:, :, None]          # [F, I(child e), I]
         child_ext_bool = later & freq[:, None, :]
         child_ext = bm.pack_bool(child_ext_bool)             # [F, I, IW]
-        child_tid = item_bits[None, :, :] & node_tid[:, None, :]   # [F, I, W]
         # Children with no extensions are leaves: their FI was already emitted
         # above, so pushing them would only burn a trip — skip them.
         has_ext = child_ext_bool.any(axis=-1)
@@ -293,8 +309,24 @@ def mine_seeded(
         stk_ext = s.stk_ext.at[stack_pos].set(
             child_ext.reshape(F * I, IW), mode="drop"
         )
-        stk_tid = s.stk_tid.at[stack_pos].set(
-            child_tid.reshape(F * I, W), mode="drop"
+        # A child is (its parent's arena row, item e).  The frontier nodes
+        # that push write their tidlists once, as one block of F rows at
+        # sp_pop, in lane order: node f's row sp_pop + j (j = pushing nodes
+        # before f) is at most its first child's position.  So every node
+        # refers to a row at or below its own stack position, and rows at or
+        # above sp_pop are referred to by nothing left after this trip's pops
+        # (which read the arena before this write).  The arena has F spare
+        # rows so the block never clamps.
+        lane_push = push.any(axis=-1)                        # [F]
+        row_f = sp_pop + jnp.cumsum(lane_push) - lane_push   # [F]
+        block = node_tid[jnp.argsort(~lane_push, stable=True)]
+        arena = jax.lax.dynamic_update_slice(s.arena, block, (sp_pop, 0))
+        stk_ref = s.stk_ref.at[stack_pos].set(
+            jnp.broadcast_to(row_f[:, None], (F, I)).reshape(F * I),
+            mode="drop",
+        )
+        stk_item = s.stk_item.at[stack_pos].set(
+            jnp.tile(jnp.arange(I, dtype=jnp.int32), F), mode="drop"
         )
         sp_new = jnp.minimum(sp_pop + n_push, S)
 
@@ -302,7 +334,9 @@ def mine_seeded(
             sp=sp_new,
             stk_items=stk_items,
             stk_ext=stk_ext,
-            stk_tid=stk_tid,
+            stk_ref=stk_ref,
+            stk_item=stk_item,
+            arena=arena,
             out_items=out_items,
             out_supp=out_supp,
             n_out=n_out,
@@ -314,6 +348,7 @@ def mine_seeded(
             key=key,
             it=s.it + 1,
             popped=s.popped + active.sum().astype(jnp.int32),
+            pushed=s.pushed + (sp_new - sp_pop),
         )
 
     with jax.named_scope(scope):
@@ -328,6 +363,7 @@ def mine_seeded(
         reservoir_supports=final.res_supp,
         n_iters=final.it,
         n_popped=final.popped,
+        n_pushed=final.pushed,
     )
 
 

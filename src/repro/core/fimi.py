@@ -370,10 +370,10 @@ def _run(tx_shards, n_items, params, key, spmd, mesh, materialize, P,
         )
         out4 = jax.block_until_ready(out4)
         if tr.enabled:
-            iters, popped = jax.device_get(
-                (out4.work_iters, out4.nodes_popped))
+            iters, popped, pushed = jax.device_get(
+                (out4.work_iters, out4.nodes_popped, out4.nodes_pushed))
             sp.set(trips=int(np.max(iters)), popped=int(np.sum(popped)),
-                   P=P, K=K)
+                   pushed=int(np.sum(pushed)), P=P, K=K)
     mine_s = time.perf_counter() - mine_t0
     trips_arr = np.asarray(out4.work_iters).astype(np.float64).reshape(-1)
     # Loop-attributed kernel work: the multi-support sweep executes inside

@@ -45,11 +45,11 @@ def vertical_from_slab(
     """Horizontal packed slab ``uint32[T, IW]`` (+ row-valid mask) → vertical
     ``item_bits uint32[I, n_words(T)]`` and the valid-tid bitmap.
 
-    The transpose lives on device: unpack → mask → transpose → pack, jitted
-    so the 32×-wide unpacked intermediates fuse instead of being held.
+    The transpose lives on device, one 32×32 bit block at a time, with
+    invalid rows masked to zero first.
     """
-    dense = bm.unpack_bool(slab, n_items) & valid[:, None]   # [T, I]
-    item_bits = bm.pack_bool(dense.T)                        # [I, W]
+    rows = jnp.where(valid[:, None], slab, _U32(0))
+    item_bits = bm.transpose_bits(rows, n_items)             # [I, W]
     valid_tid = bm.pack_bool(valid)                          # [W]
     return item_bits, valid_tid
 
@@ -346,6 +346,8 @@ class Phase4Out(NamedTuple):
     work_iters: jnp.ndarray    # int32 — DFS trips (the load-balance metric)
     nodes_popped: jnp.ndarray  # int32 — DFS nodes mined; /(trips·K) is the
     #                            lane fill (args of span fimi/phase4_mine)
+    nodes_pushed: jnp.ndarray  # int32 — children pushed; /(trips·K·I) is the
+    #                            share of the frontier's child slots kept
 
 
 def phase4_mine(
@@ -407,4 +409,5 @@ def phase4_mine(
         overflow=res.stack_overflow,
         work_iters=res.n_iters,
         nodes_popped=res.n_popped,
+        nodes_pushed=res.n_pushed,
     )

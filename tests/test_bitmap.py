@@ -20,6 +20,22 @@ def test_pack_unpack_roundtrip(n, rows, seed):
     np.testing.assert_array_equal(back, dense)
 
 
+@pytest.mark.parametrize("lead,rows,words,n", [
+    ((), 1, 1, 1), ((), 31, 2, 40), ((), 32, 1, 32), ((), 100, 3, 70),
+    ((), 77, 2, 64), ((), 1000, 32, 1000), ((2, 3), 45, 2, 50),
+])
+def test_transpose_bits_matches_unpacked_transpose(lead, rows, words, n):
+    """The in-word 32×32 block transpose equals unpack → transpose → pack,
+    at every row count and column cut, tails and leading axes included."""
+    rng = np.random.default_rng(rows * words + n)
+    x = jnp.asarray(rng.integers(0, 2**32, size=(*lead, rows, words),
+                                 dtype=np.uint32))
+    want = bm.pack_bool(jnp.swapaxes(bm.unpack_bool(x, n), -1, -2))
+    got = bm.transpose_bits(x, n)
+    assert got.shape == (*lead, n, bm.n_words(rows))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @given(st.integers(0, 2**31 - 1), st.integers(1, 300))
 @settings(max_examples=30, deadline=None)
 def test_popcount_matches_numpy(seed, n):

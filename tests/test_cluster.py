@@ -381,13 +381,15 @@ def test_mine_store_spans_one_mine(traced_store_mine):
 def test_exchange_and_mine_spans_count_what_the_rounds_did(traced_store_mine):
     """``rows_moved`` and ``replication`` on ``cluster/exchange`` equal a
     host count over each round's class table; ``trips`` and ``donations``
-    on ``cluster/mine`` equal the round's RoundStats."""
+    on ``cluster/mine`` equal the round's RoundStats, and ``pushed`` counts
+    each miner's pushes, at most one per child slot of a trip."""
     dense, _, res, events, taken, _, _ = traced_store_mine
     rounds = res.report.rounds
     assert len(rounds) > 1 and len(taken) == len(rounds)
     assert sum(len(r.donations) for r in rounds) > 0
     exchange = _host_spans(events, "cluster/exchange")
     mined = _host_spans(events, "cluster/mine")
+    slots = cluster.ClusterParams().eclat.frontier_size * dense.shape[1]
     for r, ex, mi, prefixes in zip(rounds, exchange, mined, taken):
         leaving, replication = _exchange_counts(dense, 4, prefixes)
         assert ex["args"]["rows_moved"] == leaving > 0
@@ -396,6 +398,11 @@ def test_exchange_and_mine_spans_count_what_the_rounds_did(traced_store_mine):
         assert ex["args"]["overflow"] == 0
         assert mi["args"]["trips"] == r.work_iters.tolist()
         assert mi["args"]["donations"] == len(r.donations)
+        pushed = mi["args"]["pushed"]
+        assert len(pushed) == 4 and all(type(n) is int for n in pushed)
+        assert all(0 <= n <= t * slots
+                   for n, t in zip(pushed, r.work_iters.tolist()))
+    assert sum(sum(mi["args"]["pushed"]) for mi in mined) > 0
 
 
 _STORE_SCRIPT = """

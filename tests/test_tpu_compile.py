@@ -140,3 +140,13 @@ def test_cluster_phase_compiles_for_four_v5e_chips(topo, phase):
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert held < 16e9
+    if phase == "mine":
+        # The frontier loop pushes no [K·I, W] child block and writes its
+        # tidlists as one block, not row by row; the slab's transpose holds
+        # nothing 32x wider than the slab.  Unpacking the slab to transpose
+        # it, with a stack that kept every child's tidlist, compiled to
+        # 8,098,377,728 temp bytes here.
+        W4 = P * T4 // 32
+        assert f"u32[{K * I},{W4}]" not in text
+        assert f"u32[1,{W4}]" not in text
+        assert mem.temp_size_in_bytes <= 8_098_377_728 - 2.0e9

@@ -70,6 +70,9 @@ def test_phase1_and_phase4_record_loop_counts(runs):
     assert b["trips"] == int(np.max(res.work_iters))
     assert b["popped"] == int(np.sum(res.nodes_popped))
     assert 0 < b["popped"] <= P * b["trips"] * K
+    # every pushed child is popped, after the seeds
+    assert b["pushed"] == int(np.sum(np.asarray(res.phase4.nodes_pushed)))
+    assert 0 < b["pushed"] < b["popped"]
     assert b["P"] == P and b["K"] == K
     for ev in (p1, p4):
         assert all(type(v) in (int, float, str) for v in ev["args"].values())
