@@ -1,7 +1,6 @@
-"""Generate EXPERIMENTS.md §Dry-run + §Roofline tables from results/, plus
-the cross-suite ``BENCH_*.json`` summary table (one row per benchmark file:
-its headline scalars, with the regression-gated overhead/slowdown ratios
-flagged — the same keys ``obs_report baseline`` exits non-zero on)."""
+"""The cross-suite ``BENCH_*.json`` summary table (one row per benchmark
+file: its headline scalars, with the regression-gated overhead/slowdown
+ratios flagged — the same keys ``obs_report baseline`` exits non-zero on)."""
 from __future__ import annotations
 
 import json
@@ -12,7 +11,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 REPO = Path(__file__).resolve().parents[1]
-RESULTS = REPO / "results" / "dryrun"
 
 #: top-level BENCH keys that are configuration, not results
 _CONFIG_KEYS = {"bench", "backend", "db", "fast", "reps", "block_tx",
@@ -112,56 +110,8 @@ def bench_summary(root: Path = REPO, threshold: float = 0.05) -> str:
     return "\n".join(out)
 
 
-def dryrun_table(mesh: str) -> str:
-    from repro.configs.base import SHAPES, shapes_for
-    from repro.configs.registry import all_archs, get_config
-
-    out = [
-        "| arch | shape | fits 16GB | per-dev GB | args GB | HLO-wire GB/dev | "
-        "compile s | collectives |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    n_fit = n_tot = 0
-    for arch in all_archs():
-        for shape in shapes_for(get_config(arch)):
-            f = RESULTS / f"{arch}__{shape}__{mesh}.json"
-            if not f.exists():
-                out.append(f"| {arch} | {shape} | MISSING | | | | | |")
-                continue
-            r = json.loads(f.read_text())
-            if "skipped" in r:
-                out.append(f"| {arch} | {shape} | skipped | | | | | |")
-                continue
-            m, c = r["memory"], r["collectives"]
-            n_tot += 1
-            n_fit += bool(m["fits_16GB"])
-            ops = ", ".join(
-                f"{k.split('-')[-1][:4]}:{int(v/1e6)}M"
-                for k, v in sorted(c.items())
-                if k not in ("total_wire_bytes_per_device", "count")
-            )
-            out.append(
-                f"| {arch} | {shape} | {'✅' if m['fits_16GB'] else '❌'} | "
-                f"{m['per_device_total_bytes']/1e9:.1f} | "
-                f"{m['argument_bytes']/1e9:.1f} | "
-                f"{c['total_wire_bytes_per_device']/1e9:.2f} | "
-                f"{r['timing']['compile_s']:.0f} | {c['count']} ops |"
-            )
-    out.append(f"\n**{n_fit}/{n_tot} cells fit 16 GB/chip on the {mesh} mesh.**")
-    return "\n".join(out)
-
-
 def main():
-    from benchmarks import roofline
-
-    print("## §Dry-run — single pod (16×16 = 256 chips)\n")
-    print(dryrun_table("single"))
-    print("\n## §Dry-run — multi-pod (2×16×16 = 512 chips)\n")
-    print(dryrun_table("multi"))
-    print("\n## §Roofline — single pod\n")
-    rows = roofline.full_table("single")
-    print(roofline.render_markdown(rows))
-    print("\n## Benchmark suite summary (BENCH_*.json)\n")
+    print("## Benchmark suite summary (BENCH_*.json)\n")
     print(bench_summary())
 
 

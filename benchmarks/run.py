@@ -10,7 +10,6 @@
                  (§Distributed mining)
   io           → out-of-core store: streamed vs in-RAM mine throughput +
                  host high-water marks, O(block) residency gates (§Storage)
-  roofline     → EXPERIMENTS.md §Roofline  (reads results/dryrun/*.json)
 
 ``python -m benchmarks.run [--fast|--full|--smoke] [--only NAME]``.  Prints
 ``name,us_per_call,derived`` CSV lines where applicable.  Defaults to the
@@ -44,7 +43,7 @@ def main() -> None:
     fast = not args.full
 
     sections = ["kernels", "serve", "stream", "cluster", "io", "speedup",
-                "pbec", "replication", "roofline"]
+                "pbec", "replication"]
     if args.smoke:
         sections = ["kernels", "serve", "stream", "cluster", "io"]
     if args.only:
@@ -86,11 +85,6 @@ def main() -> None:
             from benchmarks import replication
 
             replication.run(fast=fast)
-        elif name == "roofline":
-            from benchmarks import roofline
-
-            rows = roofline.full_table("single")
-            print(roofline.render_markdown(rows))
         print(f"[{name}: {time.perf_counter()-t0:.1f}s]", flush=True)
 
     # one cross-suite digest over everything the sections just wrote

@@ -2,9 +2,9 @@
 
 Runs the full four-phase method over real devices when available (shard_map
 over a 1-D miner mesh) or P virtual miners on one device (vmap).  On a TPU
-pod the miner axis maps onto the 256 chips of `make_production_mesh` row- or
-column-major; on this container use --devices to fork virtual CPU devices
-(set before jax import, hence the flag is handled in __main__ preamble).
+host the miner axis maps one miner onto each chip; on a CPU host use
+--devices to fork virtual CPU devices (set before jax import, hence the flag
+is handled in __main__ preamble).
 
 Three data sources:
 

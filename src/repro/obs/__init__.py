@@ -15,8 +15,7 @@ jax-free report CLI.  See DESIGN.md, "Observability".
   * :mod:`repro.obs.slo`     — sliding-window histograms/counters and the
     SLO policy engine (windowed p50/p95/p99/QPS/shed-rate, error-budget
     burn-rate alerts with hysteresis) behind the serving front end;
-  * :mod:`repro.obs.machine` — the shared roofline machine constants
-    (factored out of ``benchmarks/roofline.py``);
+  * :mod:`repro.obs.machine` — the shared roofline machine constants;
   * :mod:`repro.obs.profile` — the kernel profiler: per-dispatch-family
     measured-vs-modeled time attribution and bound-ness verdicts;
   * :mod:`repro.obs.progress` — the sample-grounded live progress/ETA

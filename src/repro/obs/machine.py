@@ -1,17 +1,18 @@
 """Machine models: the hardware constants every roofline consumer shares.
 
 One frozen :class:`MachineModel` per target — peak arithmetic throughput,
-HBM/DRAM bandwidth, interconnect link bandwidth — factored out of
-``benchmarks/roofline.py`` so the LLM roofline tables and the mining-kernel
-profiler (:mod:`repro.obs.profile`) price work against the SAME constants
-instead of each hard-coding its own copy.  Stdlib-only and jax-free (the
-layering rule of :mod:`repro.obs`): the report CLI recomputes roofline terms
-from these numbers in contexts where jax never loads.
+HBM/DRAM bandwidth, interconnect link bandwidth — so the mining-kernel
+profiler (:mod:`repro.obs.profile`) and the report CLI price work against
+the SAME constants instead of each hard-coding its own copy.  Stdlib-only
+and jax-free (the layering rule of :mod:`repro.obs`): the report CLI
+recomputes roofline terms from these numbers in contexts where jax never
+loads.
 
 Two units of "flops" coexist deliberately:
 
-  * the LLM roofline prices bf16 MXU FLOPs (``peak_flops`` of ``TPU_V5E``
-    is the published 197 TFLOP/s bf16 figure);
+  * ``peak_flops`` is the bf16 MXU FLOP peak (for ``TPU_V5E`` the
+    published 197 TFLOP/s bf16 figure), published beside the others as a
+    ``kernels/machine/*`` gauge;
   * the mining kernels are integer word machines — one "op" is one 32-bit
     word operation (AND / popcount / add).  ``word_ops_peak`` is the
     sustained word-op throughput the kernels can reach on that target
@@ -33,7 +34,7 @@ class MachineModel:
     """Roofline constants of one execution target."""
 
     name: str
-    peak_flops: float       # bf16 FLOP/s (dense-matmul peak; LLM roofline)
+    peak_flops: float       # bf16 FLOP/s (dense-matmul peak)
     hbm_bw: float           # bytes/s main-memory bandwidth
     link_bw: float          # bytes/s per interconnect link
     word_ops_peak: float    # 32-bit word ops/s (mining-kernel peak)

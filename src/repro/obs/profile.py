@@ -9,9 +9,9 @@ family it accumulates
     by power-of-two-rounded shape, plus loop-attributed time for kernels
     that execute inside ``lax.while_loop`` (see below);
   * **modeled** time — an analytic word-op/byte cost model priced against
-    the shared :mod:`repro.obs.machine` roofline constants (factored out
-    of ``benchmarks/roofline.py``), giving per-family compute and memory
-    terms, ``modeled = max(compute, memory)``, an achieved fraction
+    the shared :mod:`repro.obs.machine` roofline constants, giving
+    per-family compute and memory terms, ``modeled = max(compute,
+    memory)``, an achieved fraction
     ``modeled / measured``, and a memory- vs compute-bound verdict.
 
 Two measurement paths

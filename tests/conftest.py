@@ -2,7 +2,7 @@ import numpy as np
 import pytest
 
 # NOTE: no XLA_FLAGS here by design — smoke tests and benches must see ONE
-# device (the dry-run sets its own 512-device flag in its own process).
+# device (multi-device tests fork their own device count in a subprocess).
 
 
 @pytest.fixture(scope="session")

@@ -231,19 +231,3 @@ def test_publish_gauge_scheme():
     # live per-bucket histogram recorded at call time
     assert any(k.startswith("kernels/pair/call_us/") for k in
                snap["histograms"])
-
-
-def test_roofline_constants_are_shared():
-    """benchmarks/roofline.py prices with the same machine constants."""
-    import sys
-    from pathlib import Path
-
-    repo = Path(__file__).resolve().parents[1]
-    sys.path.insert(0, str(repo))
-    try:
-        from benchmarks import roofline
-    finally:
-        sys.path.remove(str(repo))
-    assert roofline.PEAK == TPU_V5E.peak_flops
-    assert roofline.HBM == TPU_V5E.hbm_bw
-    assert roofline.LINK == TPU_V5E.link_bw

@@ -9,9 +9,18 @@ import pytest
 
 from repro.launch import serve_load
 from repro.obs import metrics as obs_metrics
+from repro.obs import perfdb
 from repro.obs import trace as obs_trace
 
 DB = "T0.25I0.016P6PL4TL6"      # 250 tx, 16 items: serving is under test
+
+
+@pytest.fixture(autouse=True)
+def _history(tmp_path, monkeypatch):
+    """Gated runs append to the perf trajectory: keep it out of the tree."""
+    path = tmp_path / "BENCH_HISTORY.jsonl"
+    monkeypatch.setattr(perfdb, "DEFAULT_PATH", str(path))
+    return path
 
 
 @pytest.fixture(autouse=True)
@@ -38,7 +47,8 @@ def _argv(tmp_path, **over):
     return argv + ["--no-dashboard", "--gate"]
 
 
-def test_healthy_load_passes_gate_and_records_slo_keys(tmp_path, capsys):
+def test_healthy_load_passes_gate_and_records_slo_keys(tmp_path, capsys,
+                                                       _history):
     rc = serve_load.main(_argv(tmp_path) + ["--compare-dispatch"])
     out = capsys.readouterr().out
     assert rc == 0, out
@@ -54,6 +64,9 @@ def test_healthy_load_passes_gate_and_records_slo_keys(tmp_path, capsys):
     assert bench["slo_p99_ms"] <= bench["slo_p99_objective_ms"]
     # acceptance: the fused micro-batch sweep beats per-query dispatch
     assert bench["slo_microbatch_speedup"] > 1.0
+    # the gated run's trajectory row went to the history it was pointed at
+    rows, _ = perfdb.load(str(_history))
+    assert [r["suite"] for r in rows] == ["serve_load"]
 
 
 def test_injected_overload_trips_burn_alert_and_gate(tmp_path, capsys):

@@ -1,4 +1,4 @@
-"""End-to-end behaviour: generator naming, registry, phase-3 exchange unit."""
+"""End-to-end behaviour: generator naming, phase-3 exchange unit."""
 import numpy as np
 import pytest
 
@@ -27,16 +27,6 @@ def test_ibm_generator_statistics():
     assert 5 < lens.mean() < 40  # corruption keeps it below TL but nonzero
     # deterministic
     np.testing.assert_array_equal(dense, generate_dense(p))
-
-
-def test_registry_complete():
-    from repro.configs.registry import all_archs, get_config
-
-    assert len(all_archs()) == 10
-    for a in all_archs():
-        cfg = get_config(a)
-        smoke = get_config(a, smoke=True)
-        assert cfg.family == smoke.family
 
 
 def test_phase3_exchange_unit(small_db):
