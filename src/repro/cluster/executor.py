@@ -653,21 +653,25 @@ def mine_store(
     ``report.phase_ms`` charges ``plan`` to the off-disk planning and adds
     ``assemble``.  While tracing the mine is span ``cluster/run``, with
     ``cluster/plan`` and ``cluster/assemble`` inside it beside the
-    executor's spans, all carrying ``mine``.
+    executor's spans, all carrying ``mine``; those two also carry ``crc``,
+    the CRC32C path that verified their block reads (``"c"`` or
+    ``"numpy"``).
     """
-    from repro.store import reader as store_reader
+    from repro.store import checksum, reader as store_reader
 
     mine = next(_MINES)
     tr = obs_trace.TRACER
     with tr.span("cluster/run", P=P, mine=mine):
         t0 = time.perf_counter()
-        with tr.span("cluster/plan", P=P, mine=mine):
+        with tr.span("cluster/plan", P=P, mine=mine,
+                     crc=checksum.IMPLEMENTATION):
             plan = planner_mod.plan(store, None, params.planner, key, P=P)
             if adjust_plan is not None:
                 plan = adjust_plan(plan)
         t1 = time.perf_counter()
         spmd, mesh, _ = _auto_spmd(P, None, None)
-        with tr.span("cluster/assemble", P=P, mine=mine):
+        with tr.span("cluster/assemble", P=P, mine=mine,
+                     crc=checksum.IMPLEMENTATION):
             shards = store_reader.to_device_shards(store, P)
             if mesh is not None:
                 shards = store_reader.place_on_mesh(shards, mesh)

@@ -143,7 +143,7 @@ def _run(tx_shards, n_items, params, key, spmd, mesh, materialize, P,
          host_budget_blocks, reader, mine) -> FimiResult:
     tr = obs_trace.TRACER
     if not hasattr(tx_shards, "shape"):   # a TxStore: mine out-of-core
-        from repro.store import reader as store_reader
+        from repro.store import checksum, reader as store_reader
 
         if P is None:
             raise ValueError("P (miner count) is required when mining a TxStore")
@@ -156,7 +156,8 @@ def _run(tx_shards, n_items, params, key, spmd, mesh, materialize, P,
         # included) matches the in-memory path bit for bit.  Drivers pass
         # ``reader`` (a BlockReader on this store) to observe the streamed
         # host high-water mark of this very pass.
-        with tr.span("fimi/assemble_store", P=P, mine=mine):
+        with tr.span("fimi/assemble_store", P=P, mine=mine,
+                     crc=checksum.IMPLEMENTATION):
             tx_shards = tr.sync(store_reader.to_device_shards(
                 tx_shards, P, host_budget_blocks=host_budget_blocks,
                 reader=reader,

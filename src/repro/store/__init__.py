@@ -9,7 +9,8 @@
              streamed exact support counting.
 ``fimi_io``  Standard FIMI ``.dat`` parse / write / streamed ingest with
              dense-id remapping and inverse label map.
-``checksum`` CRC32C (Castagnoli) in vectorized numpy — per-block payload
+``checksum`` CRC32C (Castagnoli), by ``google_crc32c``'s C extension where
+             it imports, else in vectorized numpy — per-block payload
              integrity, verified on every read.
 ``fsck``     Scan / repair / quarantine: classifies every damage class of
              the failure model, adopts a crashed writer's residue.

@@ -33,7 +33,8 @@ from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 
-from repro.store.checksum import crc32c
+from repro.obs import metrics as obs_metrics
+from repro.store import checksum
 
 MANIFEST_NAME = "manifest.json"
 BLOCK_DIR = "blocks"
@@ -343,7 +344,7 @@ class StoreWriter:
                 sketch_items=[int(i) for i in top],
                 sketch_counts=[int(counts[i]) for i in top],
                 n_bytes=int(packed.nbytes),
-                crc32c=crc32c(packed),
+                crc32c=checksum.crc32c(packed),
             )
         )
         self.manifest.n_tx += int(packed.shape[0])
@@ -450,7 +451,9 @@ class TxStore:
         :class:`StaleManifestError` / :class:`ChecksumMismatchError` — each
         damage class is distinct so callers (and the fsck CLI) can act on
         it.  OS-level read failures propagate as ``OSError`` for the
-        reader's retry policy.
+        reader's retry policy.  Each verified payload counts once in the
+        registry counter ``store/crc_blocks_c`` or ``store/crc_blocks_numpy``,
+        after the CRC32C path that checked it.
         """
         meta = self.manifest.blocks[i]
         path = os.path.join(self.directory, meta.file)
@@ -480,7 +483,9 @@ class TxStore:
                 f"{meta.n_bytes}B at {path}"
             )
         if self.verify and meta.crc32c is not None:
-            got = crc32c(np.ascontiguousarray(arr))
+            impl = checksum.IMPLEMENTATION
+            got = checksum.crc32c(np.ascontiguousarray(arr))
+            obs_metrics.registry().counter(f"store/crc_blocks_{impl}").inc()
             if got != meta.crc32c:
                 raise ChecksumMismatchError(
                     f"block {i}: CRC32C {got:#010x} != manifest "

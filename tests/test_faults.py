@@ -2,6 +2,7 @@
 classification/repair, writer crash-window resume, and bit-exact
 kill-and-resume of the distributed executor — every claim of DESIGN.md's
 "Failure model", driven through the ``tests/faults.py`` injectors."""
+import functools
 import json
 import os
 import threading
@@ -19,6 +20,8 @@ from faults import (
     orphan_block,
 )
 from repro.core import eclat, fimi
+from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.store import (
     BlockReader,
     ChecksumMismatchError,
@@ -34,6 +37,7 @@ from repro.store import (
     crc32c,
     fsck,
 )
+from repro.store import checksum
 from repro.store.checksum import crc32c_ref
 from repro.store.reader import BlockReadError
 
@@ -88,6 +92,46 @@ def test_crc32c_uint32_payload_and_sensitivity():
     assert c0 == crc32c_ref(arr.view(np.uint8).reshape(-1))
     arr[7, 1] ^= np.uint32(1 << 13)  # single bit flip must change the CRC
     assert crc32c(arr) != c0
+
+
+# Each CRC32C path by name; the C arm runs where google_crc32c's C extension
+# imports, and only the C arm skips where it does not.
+_CRC_PATHS = {"c": checksum.crc32c_c, "numpy": checksum.crc32c_numpy}
+_PATH_ARMS = [
+    pytest.param("c", marks=pytest.mark.skipif(
+        checksum.IMPLEMENTATION != "c",
+        reason="google_crc32c's C extension is not importable")),
+    "numpy",
+]
+_BLOCK_BYTES = 4096 * 32 * 4          # one 4,096-row, 32-word store block
+
+
+@functools.lru_cache(maxsize=None)
+def _random_bytes_and_ref(n):
+    rng = np.random.default_rng(n)
+    data = rng.integers(0, 256, size=n, dtype=np.uint8)
+    return data, crc32c_ref(data)
+
+
+@pytest.mark.parametrize("path", _PATH_ARMS)
+@pytest.mark.parametrize(
+    "n", [0, 1, 3, 7, 63, 64, 65, 255, 1024, 4097, _BLOCK_BYTES])
+def test_crc32c_paths_agree_with_reference(path, n):
+    data, want = _random_bytes_and_ref(n)
+    assert _CRC_PATHS[path](data) == want
+    assert _CRC_PATHS[path](data.tobytes()) == want
+
+
+@pytest.mark.parametrize("path", _PATH_ARMS)
+def test_crc32c_paths_check_value_and_strided_view(path):
+    crc = _CRC_PATHS[path]
+    assert crc(b"123456789") == 0xE3069283            # RFC 3720 B.4
+    rng = np.random.default_rng(1)
+    arr = rng.integers(0, 1 << 32, size=(64, 12), dtype=np.uint32)
+    for view in (arr[:, ::3], arr.T, arr[::2]):
+        assert not view.flags.c_contiguous
+        flat = np.ascontiguousarray(view).view(np.uint8).reshape(-1)
+        assert crc(view) == crc32c_ref(flat)
 
 
 # ---------------------------------------------------------------------------
@@ -224,6 +268,54 @@ def test_verify_off_skips_checksum_only(tmp_path):
     st.read_block(0)                      # geometry intact ⇒ readable
     with pytest.raises(ChecksumMismatchError):
         TxStore.open(s.directory).read_block(0)
+
+
+def _crc_counts():
+    reg = obs_metrics.registry()
+    return {p: reg.counter(f"store/crc_blocks_{p}").value for p in _CRC_PATHS}
+
+
+@pytest.mark.parametrize("path", _PATH_ARMS)
+def test_each_crc_path_verifies_reads_and_refuses_a_flipped_bit(
+        tmp_path, monkeypatch, path):
+    monkeypatch.setattr(checksum, "IMPLEMENTATION", path)
+    dense = _random_dense(96, 16, seed=9)
+    s = _store_from_dense(tmp_path, dense, [32, 32, 32])
+    assert [b.crc32c for b in s.manifest.blocks] == [
+        crc32c_ref(np.ascontiguousarray(s.read_block(i)).view(np.uint8))
+        for i in range(3)]
+    want = TxStore.open(s.directory, verify=False).read_all_packed()
+    before = _crc_counts()
+    np.testing.assert_array_equal(
+        TxStore.open(s.directory).read_all_packed(), want)
+    after = _crc_counts()
+    assert {p: after[p] - before[p] for p in after} == {
+        p: (3 if p == path else 0) for p in after}
+    corrupt_block(s.directory, 1, "bitflip")
+    with pytest.raises(ChecksumMismatchError, match="block_000001"):
+        TxStore.open(s.directory).read_block(1)
+    assert _crc_counts()[path] == after[path] + 1
+
+
+@pytest.mark.parametrize("path", _PATH_ARMS)
+def test_mine_from_store_names_its_crc_path(tmp_path, monkeypatch, path):
+    monkeypatch.setattr(checksum, "IMPLEMENTATION", path)
+    dense = _random_dense(128, 16, seed=10)
+    s = _store_from_dense(tmp_path, dense, [32, 32, 32, 32])
+    tr = obs_trace.TRACER
+    tr.clear()
+    before = _crc_counts()
+    tr.enable()
+    try:
+        fimi.run(TxStore.open(s.directory), None, _fimi_params(),
+                 jax.random.PRNGKey(0), materialize=True, P=2)
+    finally:
+        tr.disable()
+    (span,) = [e for e in tr.export()["traceEvents"]
+               if e.get("ph") == "X" and e["name"] == "fimi/assemble_store"]
+    tr.clear()
+    assert span["args"]["crc"] == path
+    assert _crc_counts()[path] - before[path] == s.n_blocks
 
 
 # ---------------------------------------------------------------------------
